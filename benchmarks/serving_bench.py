@@ -90,6 +90,7 @@ import numpy as np
 from benchmarks.common import csv_row
 from benchmarks.streaming_bench import _checkpointify
 from repro.configs import get_config
+from repro.launch.compile_cache import setup_compile_cache
 from repro.nn.model import init_params
 from repro.serving import (EngineModel, FlightRecorder, InstallCostModel,
                            SchedulerConfig, ServingEngine, SLOConfig,
@@ -793,7 +794,7 @@ def _run_kernel_arm(cfg, params, jobs, *, backend: str, fuse: bool):
                      kernel_backend=backend)],
         sched=SchedulerConfig(max_prefill_per_step=2),
         clock=clock, tracer=Tracer(),
-        fuse_sampling=fuse, kernel_interpret=True)
+        fuse_sampling=fuse)
     pending = sorted((t, i) for i, (t, *_rest) in enumerate(jobs))
     for _ in range(100_000):
         if not pending and not eng.has_work():
@@ -1200,6 +1201,7 @@ def main(argv=None) -> dict:
                         "(events.jsonl, prom.txt, health.json, flight "
                         "dumps) in this directory instead of a tempdir")
     args = p.parse_args(argv)
+    setup_compile_cache()
     parts = sorted({int(x) for x in args.parts.split(",") if x.strip()})
 
     results = {}

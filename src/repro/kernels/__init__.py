@@ -1,3 +1,17 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels: each package holds kernel.py, its ops.py wrapper and a
+pure-jnp ref.py oracle."""
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted on the current platform:
+    compiled on TPU, interpreted on CPU (tests and rehearsals).  Any other
+    platform is an error rather than a silent fall back to the slow
+    interpreter."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels target TPU (or CPU in interpret "
+                       f"mode); no mode for platform {platform!r}")

@@ -37,12 +37,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, causal: bool,
 
     def body(j, carry):
         m, l, acc = carry
-        # NB: dslice (not a bare int) on the leading axis — interpret-mode
-        # discharge rejects scalar int indices in pl.load tuples.
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)   # (bk, D)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)   # (bk, D)
+        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = q @ k.T                                    # (bq, bk)
         qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)

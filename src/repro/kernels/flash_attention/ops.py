@@ -4,6 +4,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 
@@ -20,8 +21,8 @@ def flash_attention(q, k, v, causal: bool = True, bq: int = 128, bk: int = 128):
     qt = qt.reshape(B * H, Sq + pq, D)
     kt = kt.reshape(B * H, Skv + pk, D)
     vt = vt.reshape(B * H, Skv + pk, D)
-    interpret = jax.default_backend() != "tpu"
     o = flash_attention_pallas(qt, kt, vt, causal=causal, bq=bq, bk=bk,
-                               interpret=interpret, seq_kv_valid=Skv)
+                               interpret=interpret_mode(),
+                               seq_kv_valid=Skv)
     o = o.reshape(B, H, Sq + pq, D).transpose(0, 2, 1, 3)
     return o[:, :Sq]

@@ -1,19 +1,25 @@
 """Pallas paged-attention decode kernel, TPU-targeted.
 
 Batched single-token decode over a paged KV layout: K/V live in a page
-pool (P, page, Hkv, D) and each batch row addresses its sequence through a
-page table (B, T) of physical page ids (`repro.serving.paging` builds
-both).
+pool (P, page, Hkv·D) — the KV heads flattened into the lane axis, so a
+page is one lane-dense (page, Hkv·D) tile whatever the head count, and a
+head_dim of 64 wastes no lanes — and each batch row addresses its sequence
+through a page table (B, T) of physical page ids (`repro.serving.paging`
+builds both).
 
-Grid: (batch, kv_heads).  Each program holds one row's G grouped query
-heads and streams that row's page table with the online-softmax recurrence:
-for logical block t it reads the physical page id from the table, gathers
-the (page, D) K/V tile out of the pool with a dynamic dslice, masks
-positions beyond the row's current position, and folds the tile into the
-running (max, denom, acc) — the FlashAttention-2 schedule over a scattered
-KV layout.  The fori_loop upper bound is pos // page + 1, so fully-masked
-tail blocks are never touched (real work skipping, like the causal bound
-in `flash_attention`).
+Grid: (batch,).  Page tables and positions arrive through scalar prefetch
+(SMEM), and the pools stay in HBM (`memory_space=ANY`): each program walks
+its row's table and DMAs one whole page of K and of V into VMEM as the
+loop reaches it, double-buffered so page t+1 streams in while page t
+computes.  Every KV head of the row is served from the same page tile:
+the query arrives as a block-diagonal (Hkv, Hkv·D) matrix per query group
+(row h holds head h's query in lanes [h·D, (h+1)·D), zeros elsewhere), so
+scores are one MXU matmul against the page, (Hkv, page), and the
+probabilities times the page give (Hkv, Hkv·D), whose block diagonal is
+each head's output.  The online-softmax recurrence (FlashAttention-2 over
+a scattered KV layout) folds each page into a lane-flat (1, Hkv·D)
+accumulator.  The fori_loop upper bound is pos // page + 1, so pages past
+the row's position are never read.
 
 Contract matches `repro.kernels.paged_attention.ref.paged_attention_ref`
 (its jnp gather math is the oracle in tests, and mirrors the paged decode
@@ -27,71 +33,120 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1.0e30
 
 
-def _kernel(q_ref, k_ref, v_ref, tab_ref, pos_ref, o_ref, *, page: int,
-            scale: float):
-    # q_ref (1, 1, G, D); k/v_ref (P, page, 1, D); tab_ref (1, T);
-    # pos_ref (1,); o_ref (1, 1, G, D)
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
-    G, D = q.shape
-    pos = pos_ref[0]
-    hi = pos // page + 1                                  # blocks holding
-    # positions ≤ pos; everything past is fully masked — skip it.
+def _kernel(tab_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
+            *, page: int, head_dim: int, n_blocks: int, scale: float):
+    # tab_ref (B*T,) / pos_ref (B,) in SMEM; q_ref (1, G, Hkv, Hkv*D)
+    # block-diagonal queries; o_ref (1, G, 1, Hkv*D); k/v_hbm
+    # (P, page, Hkv*D) in HBM; k/v_buf (2, page, Hkv*D) in VMEM
+    b = pl.program_id(0)
+    G, Hkv, HD = q_ref.shape[1:]
+    pos = pos_ref[b]
+    hi = pos // page + 1                # blocks holding positions <= pos
+
+    def copies(t, slot):
+        pid = tab_ref[b * n_blocks + t]
+        return (pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[slot],
+                                      sem.at[1, slot]))
+
+    for c in copies(0, 0):
+        c.start()
+
+    # own[h, i]: lane i belongs to head h — picks each head's block of the
+    # (Hkv, Hkv*D) products and spreads per-head scalars over their lanes
+    own = (jax.lax.broadcasted_iota(jnp.int32, (Hkv, HD), 1) // head_dim
+           == jax.lax.broadcasted_iota(jnp.int32, (Hkv, HD), 0))
+
+    def spread(x):                      # (Hkv, 1) -> (1, Hkv*D)
+        return jnp.sum(jnp.where(own, x, 0.0), axis=0, keepdims=True)
 
     def body(t, carry):
-        m, l, acc = carry
-        pid = tab_ref[0, t]
-        # NB: dslice (not a bare int) on the leading axis — interpret-mode
-        # discharge rejects scalar int indices in pl.load tuples.
-        k = pl.load(k_ref, (pl.dslice(pid, 1), slice(None), pl.dslice(0, 1),
-                            slice(None)))[0, :, 0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(pid, 1), slice(None), pl.dslice(0, 1),
-                            slice(None)))[0, :, 0].astype(jnp.float32)
-        s = q @ k.T                                       # (G, page)
-        kpos = t * page + jax.lax.broadcasted_iota(jnp.int32, (G, page), 1)
-        s = jnp.where(kpos <= pos, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        acc_new = acc * corr[:, None] + p @ v
-        return m_new, l_new, acc_new
+        slot = t % 2
 
-    m0 = jnp.full((G,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((G,), jnp.float32)
-    a0 = jnp.zeros((G, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, a0))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        @pl.when(t + 1 < hi)
+        def _():
+            for c in copies(t + 1, 1 - slot):
+                c.start()
+
+        for c in copies(t, slot):
+            c.wait()
+        k = k_buf[slot]                                   # (page, Hkv*D)
+        v = v_buf[slot]
+        kpos = t * page + jax.lax.broadcasted_iota(jnp.int32, (Hkv, page), 1)
+        out = []
+        for g, (m, l, acc) in enumerate(carry):
+            s = jax.lax.dot_general(
+                q_ref[0, g].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (Hkv, page)
+            s = jnp.where(kpos <= pos, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)                        # (Hkv, 1)
+            l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+            pv = jnp.dot(p, v.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)  # (Hkv, Hkv*D)
+            acc_new = (acc * spread(corr)
+                       + jnp.sum(jnp.where(own, pv, 0.0), axis=0,
+                                 keepdims=True))
+            out.append((m_new, l_new, acc_new))
+        return tuple(out)
+
+    init = tuple((jnp.full((Hkv, 1), NEG_INF, jnp.float32),
+                  jnp.zeros((Hkv, 1), jnp.float32),
+                  jnp.zeros((1, HD), jnp.float32)) for _ in range(G))
+    final = jax.lax.fori_loop(0, hi, body, init)
+    for g, (_, l, acc) in enumerate(final):
+        o_ref[0, g] = (acc / spread(jnp.maximum(l, 1e-30))).astype(
+            o_ref.dtype)
 
 
 def paged_attention_pallas(
-    q: jax.Array,        # (B, Hkv, G, D) grouped query heads
-    k_pool: jax.Array,   # (P, page, Hkv, D)
+    q: jax.Array,        # (B, G, Hkv, D) query heads, group-major
+    k_pool: jax.Array,   # (P, page, Hkv*D)
     v_pool: jax.Array,
     tables: jax.Array,   # (B, T) int32 physical page ids
     pos: jax.Array,      # (B,) int32 current position per row
     *,
-    interpret: bool = False,
+    interpret: bool,
 ) -> jax.Array:
-    B, Hkv, G, D = q.shape
-    P, page = k_pool.shape[:2]
+    """Returns (B, G, Hkv, D)."""
+    B, G, Hkv, D = q.shape
+    page, HD = k_pool.shape[1:]
     T = tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
-    kern = functools.partial(_kernel, page=page, scale=scale)
-    return pl.pallas_call(
-        kern,
-        grid=(B, Hkv),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((P, page, 1, D), lambda b, h: (0, 0, h, 0)),
-            pl.BlockSpec((P, page, 1, D), lambda b, h: (0, 0, h, 0)),
-            pl.BlockSpec((1, T), lambda b, h: (b, 0)),
-            pl.BlockSpec((1,), lambda b, h: (b,)),
+    # block-diagonal queries: qd[b, g, h, h'*D + d] = q[b, g, h, d] iff h == h'
+    qd = jnp.einsum("bghd,hk->bghkd", q, jnp.eye(Hkv, dtype=q.dtype))
+    qd = qd.reshape(B, G, Hkv, HD)
+    kern = functools.partial(_kernel, page=page, head_dim=D, n_blocks=T,
+                             scale=1.0 / math.sqrt(D))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, G, Hkv, HD),
+                               lambda b, tab, pos: (b, 0, 0, 0)),
+                  hbm, hbm],
+        out_specs=pl.BlockSpec((1, G, 1, HD),
+                               lambda b, tab, pos: (b, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, page, HD), k_pool.dtype),
+            pltpu.VMEM((2, page, HD), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+    )
+    o = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, G, 1, HD), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(q, k_pool, v_pool, tables, pos)
+        name="paged_attention",
+    )(tables.reshape(-1), pos, qd, k_pool, v_pool)
+    return o.reshape(B, G, Hkv, D)

@@ -7,11 +7,12 @@ import jax.numpy as jnp
 
 
 def paged_attention_ref(q, k_pool, v_pool, tables, pos) -> jax.Array:
-    """q (B, H, D); k/v_pool (P, page, Hkv, D); tables (B, T) int32;
+    """q (B, H, D); k/v_pool (P, page, Hkv*D); tables (B, T) int32;
     pos (B,) int32.  Returns (B, H, D): one decode step attending over
     positions ≤ pos[b] gathered through each row's page table."""
     B, H, D = q.shape
-    P, page, Hkv, _ = k_pool.shape
+    P, page, HD = k_pool.shape
+    Hkv = HD // D
     T = tables.shape[1]
     G = H // Hkv
     scale = 1.0 / math.sqrt(D)

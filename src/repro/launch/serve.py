@@ -16,6 +16,7 @@ import json
 import signal
 import sys
 import time
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -23,8 +24,9 @@ import numpy as np
 
 from repro.configs import ARCHS, get_config, supported_shapes
 from repro.data.pipeline import DataConfig, make_batch
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.steps import cached_prefill_step, cached_serve_step
-from repro.nn.model import init_params
+from repro.nn.model import init_params, init_serving_params
 
 
 def _json_safe(obj):
@@ -38,13 +40,15 @@ def _json_safe(obj):
     return obj
 
 
-def _run_engine(args) -> None:
-    """Continuous batching across ≥ 2 tenants on one device budget."""
+def build_engine(args, clock: Callable[[], float] = time.perf_counter):
+    """The continuous-batching engine `--engine` serves: two tenants on one
+    device budget, tenant-b a perturbed variant of tenant-a (the co-hosted
+    fine-tune regime where cross-tenant §V-C delta installs have real
+    structure), their matmul weights bf16 on the device, and every engine
+    knob taken from `args` (this module's parser)."""
     from repro.serving import (EngineModel, FlightRecorder, InstallCostModel,
-                               PromEndpoint, SchedulerConfig, ServingEngine,
-                               SLOConfig, TelemetryConfig, Tracer,
-                               VirtualClock, drive_simulated, format_summary,
-                               prometheus_text)
+                               SchedulerConfig, ServingEngine, SLOConfig,
+                               TelemetryConfig, Tracer)
     from repro.serving.variants import perturbed_variant
 
     cfg = get_config(args.arch, smoke=args.smoke)
@@ -55,9 +59,7 @@ def _run_engine(args) -> None:
         raise SystemExit("--kernel-backend pallas needs --kv-layout paged "
                          "(the kernel reads a page pool)")
     max_seq = args.prompt_len + args.gen + 8
-    base = init_params(jax.random.PRNGKey(0), cfg)
-    # tenant-b is a perturbed variant of tenant-a (the co-hosted fine-tune
-    # regime where cross-tenant §V-C delta installs have real structure).
+    base = init_serving_params(jax.random.PRNGKey(0), cfg)
     variant = perturbed_variant(base)
     kv = dict(kv_slots=args.kv_slots, max_seq=max_seq,
               kv_layout=args.kv_layout, page_size=args.page_size,
@@ -72,13 +74,9 @@ def _run_engine(args) -> None:
     # cross-tenant delta installs when the scheduler switches models.
     weight_slots = (args.weight_slots if args.weight_slots
                     else cfg.n_layers + 1)
-    # Structured tracing costs nothing unless asked for: a wall-clock
-    # Tracer feeds both the Chrome-trace export and the per-step
-    # component_s breakdown in the summary.  --virtual-clock swaps the
-    # wall clock for a VirtualClock and drives arrivals in simulated
-    # time, so every artifact (trace, health, flight dumps, events) is
-    # byte-deterministic — the CI telemetry-validation mode.
-    clock = VirtualClock() if args.virtual_clock else time.perf_counter
+    # Structured tracing costs nothing unless asked for: a Tracer on the
+    # engine's clock feeds both the Chrome-trace export and the per-step
+    # component_s breakdown in the summary.
     tracer = Tracer(clock=clock) if args.trace_out else None
 
     # Live telemetry plane: declared SLO targets + windowed percentiles
@@ -98,7 +96,7 @@ def _run_engine(args) -> None:
     recorder = (FlightRecorder(args.flight_recorder_steps,
                                out_dir=args.flight_dir)
                 if args.flight_recorder_steps else None)
-    eng = ServingEngine(
+    return ServingEngine(
         tenants, weight_arena_slots=weight_slots, tracer=tracer,
         clock=clock, telemetry=telemetry, recorder=recorder,
         stall_timeout_s=args.stall_timeout_s,
@@ -118,6 +116,23 @@ def _run_engine(args) -> None:
         wear_aware=args.wear_aware,
         fault_rate=args.fault_rate,
         fault_seed=args.fault_seed)
+
+
+def _run_engine(args) -> None:
+    """Continuous batching across ≥ 2 tenants on one device budget."""
+    from repro.serving import (PromEndpoint, VirtualClock, drive_simulated,
+                               format_summary, prometheus_text)
+
+    # --virtual-clock swaps the wall clock for a VirtualClock and drives
+    # arrivals in simulated time, so every artifact (trace, health, flight
+    # dumps, events) is byte-deterministic — the CI telemetry-validation
+    # mode.
+    clock = VirtualClock() if args.virtual_clock else time.perf_counter
+    eng = build_engine(args, clock)
+    cfg = next(iter(eng.models.values())).cfg
+    tenants = list(eng.models)
+    tracer, recorder = eng.tracer, eng.recorder
+    weight_slots = eng.residency.arena_slots
     if recorder is not None:
         # live-incident hooks: kill -USR1 <pid> snapshots the ring of a
         # running replica; an unhandled crash dumps it on the way down
@@ -182,7 +197,7 @@ def _run_engine(args) -> None:
         # events — is byte-reproducible, no device clock involved
         t, vjobs = 0.0, []
         for i in range(args.requests):
-            model = tenants[i % len(tenants)].name
+            model = tenants[i % len(tenants)]
             plen = int(rng.integers(max(args.prompt_len // 2, 2),
                                     args.prompt_len + 1))
             prompt = rng.integers(1, cfg.vocab, plen).tolist()
@@ -191,7 +206,7 @@ def _run_engine(args) -> None:
         summary = drive_simulated(eng, clock, vjobs, dt=0.002)
     else:
         for i in range(args.requests):
-            model = tenants[i % len(tenants)].name
+            model = tenants[i % len(tenants)]
             plen = int(rng.integers(max(args.prompt_len // 2, 2),
                                     args.prompt_len + 1))
             prompt = rng.integers(1, cfg.vocab, plen).tolist()
@@ -208,7 +223,7 @@ def _run_engine(args) -> None:
         endpoint.close()
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", choices=ARCHS, default="gemma-7b")
     p.add_argument("--smoke", action="store_true")
@@ -358,8 +373,12 @@ def main() -> None:
                    help="engine: run on a VirtualClock with deterministic "
                         "simulated arrivals — every artifact is "
                         "byte-reproducible (the CI telemetry mode)")
-    args = p.parse_args()
+    return p
 
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
+    setup_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if "decode_32k" not in supported_shapes(args.arch):
         raise SystemExit(f"{args.arch} is encoder-only: no decode path")

@@ -23,6 +23,7 @@ from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs import ARCHS, get_config
 from repro.data.pipeline import DataConfig, make_batch
 from repro.ft import StepTimer, Watchdog
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.launch.steps import make_train_step, param_shardings, opt_shardings, batch_shardings
 from repro.nn.model import init_params
@@ -45,6 +46,7 @@ def main() -> None:
                    default="none")
     p.add_argument("--watchdog-s", type=float, default=600.0)
     args = p.parse_args()
+    setup_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     data = DataConfig(seq_len=args.seq, global_batch=args.batch)

@@ -296,17 +296,18 @@ def attention(
       this chunk's K/V land at absolute positions [cache_pos, cache_pos+S)
       of a full-length staging cache (windowed layers store every position
       and mask the window; no ring until arena install)
-    * paged decode: cache leaves are page pools (P, page, ...) and
-      page_table (B, T) maps each row's logical blocks to physical pages
-      (cache_pos must be a per-row (B,) vector)
+    * paged decode: cache leaves are page pools (P, page, ...) — GQA K/V
+      pools (P, page, Hkv*D) — and page_table (B, T) maps each row's
+      logical blocks to physical pages (cache_pos must be a per-row (B,)
+      vector)
 
     kernel_backend="pallas" routes eligible paged GQA decode
     (single-token, no sliding window, no logit softcap) through the
     Pallas paged-attention kernel, which walks only the pages at or
     below each row's position instead of gathering the full table
     width; everything else falls back to the XLA path.
-    kernel_interpret pins the kernel's interpret mode (CI equivalence
-    off-TPU) — both are trace-time constants.
+    kernel_interpret is the kernel's interpret mode (the engine passes
+    `repro.kernels.interpret_mode()`) — both are trace-time constants.
     """
     B, S, _ = x.shape
     if positions is None:
@@ -343,14 +344,14 @@ def attention(
                 # gather-then-dequant.
                 new_cache = _paged_scatter_gqa(cache, k, v, cfg,
                                                cache_pos, page_table)
+                H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
                 if cfg.kv_cache_dtype == "int8":
-                    kp = _kv_dequant(new_cache["k"], new_cache["k_scale"],
-                                     k.dtype)
-                    vp = _kv_dequant(new_cache["v"], new_cache["v_scale"],
-                                     v.dtype)
+                    kp = _pool_dequant(new_cache["k"], new_cache["k_scale"],
+                                       k.dtype)
+                    vp = _pool_dequant(new_cache["v"], new_cache["v_scale"],
+                                       v.dtype)
                 else:
                     kp, vp = new_cache["k"], new_cache["v"]
-                H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
                 o = _pallas_paged(q[:, 0].reshape(B, H, D), kp, vp,
                                   page_table, cache_pos,
                                   interpret=kernel_interpret)
@@ -521,43 +522,52 @@ def _paged_ops(pool_leaf, cache_pos, page_table):
     return scatter, gather
 
 
-def _paged_append_gqa(cache, k, v, cfg: ModelConfig, cache_pos, page_table):
-    """Paged decode append + gather for GQA caches: k/v pools
-    (P, page, Hkv, D) (+ int8 scales (P, page, Hkv)); k/v are this step's
-    (B, 1, Hkv, D) projections.  Returns (kc, vc, new_cache) with kc/vc
-    gathered to (B, T·page, Hkv, D) in logical position order."""
-    scatter, gather = _paged_ops(cache["k"], cache_pos, page_table)
-    if cfg.kv_cache_dtype == "int8":
-        kq, ks = _kv_quant(k)
-        vq, vs = _kv_quant(v)
-        new_cache = {"k": scatter(cache["k"], kq),
-                     "v": scatter(cache["v"], vq),
-                     "k_scale": scatter(cache["k_scale"], ks),
-                     "v_scale": scatter(cache["v_scale"], vs)}
-        kc = _kv_dequant(gather(new_cache["k"]), gather(new_cache["k_scale"]),
-                         k.dtype)
-        vc = _kv_dequant(gather(new_cache["v"]), gather(new_cache["v_scale"]),
-                         v.dtype)
-    else:
-        new_cache = {"k": scatter(cache["k"], k), "v": scatter(cache["v"], v)}
-        kc, vc = gather(new_cache["k"]), gather(new_cache["v"])
-    return kc, vc, new_cache
+def _flat_heads(x: jax.Array) -> jax.Array:
+    """(..., Hkv, D) -> (..., Hkv*D): the GQA page-pool layout, which keeps
+    every page one lane-dense tile (see `serving.paging.init_page_pool`)."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+
+
+def _pool_dequant(codes: jax.Array, scale: jax.Array, dtype) -> jax.Array:
+    """int8 pool codes (..., Hkv*D) with per-(token, head) scales
+    (..., Hkv) -> dequantized (..., Hkv*D), elementwise `_kv_dequant`."""
+    Hkv = scale.shape[-1]
+    split = codes.reshape(codes.shape[:-1] + (Hkv, codes.shape[-1] // Hkv))
+    return _flat_heads(_kv_dequant(split, scale, dtype))
 
 
 def _paged_scatter_gqa(cache, k, v, cfg: ModelConfig, cache_pos, page_table):
-    """Scatter-only variant of `_paged_append_gqa` for the Pallas path:
-    writes this step's K/V into the pools and returns the updated cache
-    without materializing the (B, T·page, ...) gathered view — the kernel
-    reads the pools through the page table itself."""
+    """Write this step's (B, 1, Hkv, D) K/V projections into GQA page pools
+    (P, page, Hkv*D) (+ int8 scales (P, page, Hkv)); returns the updated
+    cache.  The Pallas path reads the pools through the page table
+    itself; `_paged_append_gqa` gathers them for the XLA path."""
     scatter, _ = _paged_ops(cache["k"], cache_pos, page_table)
     if cfg.kv_cache_dtype == "int8":
         kq, ks = _kv_quant(k)
         vq, vs = _kv_quant(v)
-        return {"k": scatter(cache["k"], kq),
-                "v": scatter(cache["v"], vq),
+        return {"k": scatter(cache["k"], _flat_heads(kq)),
+                "v": scatter(cache["v"], _flat_heads(vq)),
                 "k_scale": scatter(cache["k_scale"], ks),
                 "v_scale": scatter(cache["v_scale"], vs)}
-    return {"k": scatter(cache["k"], k), "v": scatter(cache["v"], v)}
+    return {"k": scatter(cache["k"], _flat_heads(k)),
+            "v": scatter(cache["v"], _flat_heads(v))}
+
+
+def _paged_append_gqa(cache, k, v, cfg: ModelConfig, cache_pos, page_table):
+    """Paged decode append + gather for GQA caches: scatter as
+    `_paged_scatter_gqa`, then return (kc, vc, new_cache) with kc/vc
+    gathered to (B, T·page, Hkv, D) in logical position order."""
+    new_cache = _paged_scatter_gqa(cache, k, v, cfg, cache_pos, page_table)
+    _, gather = _paged_ops(cache["k"], cache_pos, page_table)
+    shape = k.shape[:1] + (-1,) + k.shape[2:]
+    if cfg.kv_cache_dtype == "int8":
+        kc = _pool_dequant(gather(new_cache["k"]),
+                           gather(new_cache["k_scale"]), k.dtype)
+        vc = _pool_dequant(gather(new_cache["v"]),
+                           gather(new_cache["v_scale"]), v.dtype)
+    else:
+        kc, vc = gather(new_cache["k"]), gather(new_cache["v"])
+    return kc.reshape(shape), vc.reshape(shape), new_cache
 
 
 def _dus_batch(cache: jax.Array, new: jax.Array, pos) -> jax.Array:

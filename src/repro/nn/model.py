@@ -38,6 +38,34 @@ def init_params(key, cfg: ModelConfig) -> Params:
     }
 
 
+# Matmul weights: the forward pass reads each of these only through
+# `.astype(activation dtype)`, and activations are bf16, so holding them in
+# bf16 changes no computed value.  Norm scales, biases, SSM state
+# parameters, MoE routers and MLA's float32 absorbed projections (w_uk,
+# w_uv) keep their dtype.
+BF16_WEIGHTS = frozenset({
+    "table", "head", "w_q", "w_k", "w_v", "w_o", "w_up", "w_down", "w_gate",
+    "w_dkv", "w_kr", "w_in", "w_out", "w_xproj", "w_dt", "w_if", "w_gates",
+})
+
+
+def serving_params(params: Params) -> Params:
+    """`params` with every BF16_WEIGHTS leaf cast to bf16 (half the device
+    memory of float32, identical forward outputs)."""
+    def cast(path, leaf):
+        if getattr(path[-1], "key", None) in BF16_WEIGHTS:
+            return leaf.astype(jnp.bfloat16)
+        return leaf
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
+def init_serving_params(key, cfg: ModelConfig) -> Params:
+    """`serving_params(init_params(key, cfg))` on the default device, cast
+    inside one jitted init so no float32 copy of the whole model is ever
+    held there."""
+    return jax.jit(lambda k: serving_params(init_params(k, cfg)))(key)
+
+
 def param_specs(cfg: ModelConfig) -> Params:
     return {
         "embedding": embedding_specs(cfg),

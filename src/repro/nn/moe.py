@@ -221,13 +221,8 @@ def moe(params: Params, x: jax.Array, cfg: ModelConfig,
                     P("model", None, "data"))
         out_specs = (P(ba, "model", None), P())
 
-    if hasattr(jax, "shard_map"):            # jax >= 0.6
-        mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
-    else:                                    # jax <= 0.5: experimental home
-        from jax.experimental.shard_map import shard_map as _shard_map
-        mapped = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     y, aux = mapped(
         x, params["router"], params["w_gate"], params["w_up"], params["w_down"])
     aux = jnp.mean(aux)

@@ -71,6 +71,7 @@ from repro.launch.steps import (cached_chunk_prefill_step,
                                 cached_sample_tokens, cached_serve_step,
                                 cached_stage_install, cached_stage_quantize,
                                 prefill_cache_info)
+from repro.kernels import interpret_mode
 from repro.nn.config import ModelConfig
 from repro.nn.model import init_cache
 from repro.nn.transformer import layer_kind
@@ -121,7 +122,7 @@ class EngineModel:
     # Decode attention backend: "xla" gathers the full page-table width
     # per step; "pallas" routes paged GQA decode through the
     # kernels/paged_attention kernel, which walks only each row's live
-    # pages (interpret mode off-TPU — see ServingEngine kernel_interpret).
+    # pages (compiled on TPU, interpreted on CPU — repro.kernels).
     kernel_backend: str = "xla"      # "xla" | "pallas"
 
     def __post_init__(self):
@@ -140,6 +141,9 @@ class EngineModel:
             raise ValueError(
                 f"{self.name}: kernel_backend='pallas' needs "
                 "kv_layout='paged' (the kernel reads a page pool)")
+        # placed once: host (numpy) leaves would be copied to the device by
+        # every prefill and decode call that takes them
+        self.params = jax.device_put(self.params)
 
 
 class ServingEngine:
@@ -161,7 +165,6 @@ class ServingEngine:
                  fault_rate: float = 0.0,
                  fault_seed: int = 0,
                  kernel_backend: Optional[str] = None,
-                 kernel_interpret: Optional[bool] = None,
                  fuse_sampling: bool = True,
                  telemetry: Optional[TelemetryConfig] = None,
                  recorder: Optional[FlightRecorder] = None,
@@ -194,8 +197,8 @@ class ServingEngine:
         self._decode_fused: Dict[str, Optional[Callable]] = {}
         self._backend: Dict[str, str] = {}
         # kernel_backend (engine-level) overrides every paged tenant's
-        # EngineModel.kernel_backend; kernel_interpret=None resolves to
-        # interpret mode off-TPU (CI equivalence runs force True).
+        # EngineModel.kernel_backend; the platform decides whether the
+        # Pallas kernel compiles (TPU) or is interpreted (CPU).
         # fuse_sampling keeps sampling inside the jitted paged decode step
         # so logits never leave device; False splits it back out (the
         # batched sampler still makes it one device call per step).
@@ -203,9 +206,6 @@ class ServingEngine:
                 "xla", "pallas"):
             raise ValueError(f"unknown kernel_backend {kernel_backend!r} "
                              "(expected 'xla' or 'pallas')")
-        self._interpret = (jax.default_backend() != "tpu"
-                           if kernel_interpret is None
-                           else bool(kernel_interpret))
         self._fuse = bool(fuse_sampling)
         for m in models:
             if m.kv_layout == "paged":
@@ -217,7 +217,7 @@ class ServingEngine:
                     prefix_cache_pages=m.prefix_cache_pages)
                 backend = (kernel_backend if kernel_backend is not None
                            else m.kernel_backend)
-                interp = self._interpret if backend == "pallas" else False
+                interp = interpret_mode() if backend == "pallas" else False
                 self._backend[m.name] = backend
                 self._decode[m.name] = cached_paged_serve_step(
                     m.cfg, backend, interp)
@@ -230,11 +230,15 @@ class ServingEngine:
                 self._decode[m.name] = cached_serve_step(m.cfg)
                 self._decode_fused[m.name] = None
 
+        t0 = time.perf_counter()
         self.residency = WeightResidencyManager(
             {m.name: (m.params, m.cfg) for m in models},
             weight_arena_slots if weight_arena_slots is not None
             else sum(m.cfg.n_layers for m in models),
             reuse=reuse)
+        # host seconds spent quantizing every tenant into the residency
+        # store — start-up cost that grows with model width and tenants
+        self.residency_build_s = time.perf_counter() - t0
 
         # Structured tracing: NULL_TRACER (no-op, allocation-free) when
         # disabled; a shared Tracer instance otherwise, injected into the

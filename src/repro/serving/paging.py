@@ -377,7 +377,11 @@ def init_page_pool(cfg: ModelConfig, n_pages: int, page_size: int,
                    dtype=jnp.bfloat16):
     """Page-pool cache pytree: `nn.model.init_cache` with the batch axis
     reinterpreted as the page axis, except windowed layers keep full pages
-    (the paged decode path masks the window instead of ring-indexing)."""
+    (the paged decode path masks the window instead of ring-indexing) and
+    GQA K/V pools flatten the KV heads into the last axis, (P, page,
+    Hkv*D): a page is then one lane-dense tile that the Pallas decode
+    kernel can DMA whole, and a head_dim of 64 is not padded to the TPU's
+    128 lanes (which doubles a (..., Hkv, D) pool's device footprint)."""
 
     def attn_entry():
         if cfg.attn_type == "mla":
@@ -388,11 +392,10 @@ def init_page_pool(cfg: ModelConfig, n_pages: int, page_size: int,
                                     dtype),
             }
         kv_dt = jnp.int8 if cfg.kv_cache_dtype == "int8" else dtype
+        width = cfg.n_kv_heads * cfg.head_dim
         out = {
-            "k": jnp.zeros((n_pages, page_size, cfg.n_kv_heads,
-                            cfg.head_dim), kv_dt),
-            "v": jnp.zeros((n_pages, page_size, cfg.n_kv_heads,
-                            cfg.head_dim), kv_dt),
+            "k": jnp.zeros((n_pages, page_size, width), kv_dt),
+            "v": jnp.zeros((n_pages, page_size, width), kv_dt),
         }
         if cfg.kv_cache_dtype == "int8":
             out["k_scale"] = jnp.zeros(
@@ -422,15 +425,19 @@ def _cached_page_write(cfg: ModelConfig, page_size: int):
         out = []
         for seg_pool, seg_one, (_, _, scanned) in zip(pool, one, plan):
             def upd(a, o, scanned=scanned):
+                # the reshape flattens staging (..., Hkv, D) K/V into the
+                # pool's (..., Hkv*D) layout (a no-op for other leaves)
                 if scanned:  # a (L, P, ps, ...), o (L, 1, Lbuf, ...)
                     chunk = jax.lax.dynamic_slice_in_dim(
                         o[:, 0], block * page_size, page_size, axis=1)
+                    chunk = chunk.reshape(a.shape[:1] + (1,) + a.shape[2:])
                     return jax.lax.dynamic_update_slice_in_dim(
-                        a, chunk[:, None].astype(a.dtype), page, axis=1)
+                        a, chunk.astype(a.dtype), page, axis=1)
                 chunk = jax.lax.dynamic_slice_in_dim(
                     o[0], block * page_size, page_size, axis=0)
                 return jax.lax.dynamic_update_slice_in_dim(
-                    a, chunk[None].astype(a.dtype), page, axis=0)
+                    a, chunk.reshape((1,) + a.shape[1:]).astype(a.dtype),
+                    page, axis=0)
             out.append(jax.tree.map(upd, seg_pool, seg_one))
         return out
 
@@ -455,11 +462,15 @@ def _cached_page_read(cfg: ModelConfig, page_size: int):
         out = []
         for seg_one, seg_pool, (_, _, scanned) in zip(one, pool, plan):
             def upd(o, a, scanned=scanned):
+                # the reshape splits the pool's (..., Hkv*D) K/V back into
+                # staging's (..., Hkv, D) (a no-op for other leaves)
                 if scanned:  # a (L, P, ps, ...), o (L, 1, Lbuf, ...)
                     chunk = jax.lax.dynamic_slice_in_dim(a, page, 1, axis=1)
+                    chunk = chunk.reshape(chunk.shape[:3] + o.shape[3:])
                     return jax.lax.dynamic_update_slice_in_dim(
                         o, chunk.astype(o.dtype), block * page_size, axis=2)
                 chunk = jax.lax.dynamic_slice_in_dim(a, page, 1, axis=0)
+                chunk = chunk.reshape(chunk.shape[:2] + o.shape[2:])
                 return jax.lax.dynamic_update_slice_in_dim(
                     o, chunk.astype(o.dtype), block * page_size, axis=1)
 
@@ -475,6 +486,7 @@ def _cached_page_read(cfg: ModelConfig, page_size: int):
                                                      axis=axis)
                     s = jax.lax.dynamic_slice_in_dim(pa[f + "_scale"], page,
                                                      1, axis=axis)
+                    c = c.reshape(s.shape + (-1,))
                     deq = (c.astype(jnp.float32)
                            * s[..., None]).astype(po[f].dtype)
                     ent[f] = jax.lax.dynamic_update_slice_in_dim(

@@ -283,11 +283,18 @@ class RadixPrefixCache:
             node = child
             if len(edge) < self.page_size:
                 break
-        if self.max_cached is not None:
-            while self.n_cached > self.max_cached:
-                if not self.evict_lru(lambda p: True, free_ref):
-                    break
+        self._enforce_cap(free_ref)
         return gained
+
+    def _enforce_cap(self, free_ref: Callable[[int], None]) -> None:
+        """LRU-evict retained leaves while more than `max_cached` pages are
+        retained.  A retained page with live children cannot go yet; it is
+        evicted once they drop (`drop_page`)."""
+        if self.max_cached is None:
+            return
+        while self.n_cached > self.max_cached:
+            if not self.evict_lru(lambda p: True, free_ref):
+                break
 
     # ----------------------------------------------------------- removal
     def drop_page(self, page: int, free_ref: Callable[[int], None]) -> None:
@@ -318,6 +325,7 @@ class RadixPrefixCache:
                 free_ref(sub.page)
         self._recompute_up(parent)
         self._heap_push(parent)    # parent may have just become a leaf
+        self._enforce_cap(free_ref)
 
     # ---------------------------------------------------------- eviction
     def _evictable_leaf(self, sole: Callable[[int], bool],

@@ -14,7 +14,9 @@ single model.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Iterable, List, Optional, Set, Tuple
+import os
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Dict, Iterable, Iterator, List, Optional, Set, Tuple
 
 import jax
 import numpy as np
@@ -27,19 +29,25 @@ from repro.streaming.executor import _split_block_params
 from repro.streaming.plan import InstallCostModel
 
 
-def model_layer_tensors(params: Any, cfg: ModelConfig) -> List[List[np.ndarray]]:
-    """Per-layer big (quantizable) tensors, mirroring StreamingExecutor's
-    block extraction: scanned segments are unstacked into individual layers."""
-    blocks = []
+# each pair-costing thread holds a few chunk-sized scratch arrays
+_MAX_COST_WORKERS = 16
+
+
+def model_layer_tensors(params: Any, cfg: ModelConfig
+                        ) -> Iterator[List[np.ndarray]]:
+    """Per-layer big (quantizable) tensors as float32 host arrays, one layer
+    at a time, mirroring StreamingExecutor's block extraction: scanned
+    segments are unstacked into individual layers.  A generator, so a
+    consumer that quantizes as it goes holds one layer's float copy on the
+    host, not every tenant's whole model."""
     for seg_params, (start, length, scanned) in zip(
             params["stack"]["segments"], stack_plan(cfg)):
         if scanned:
-            blocks.extend(
-                jax.tree.map(lambda a, i=i: np.asarray(a[i]), seg_params)
-                for i in range(length))
+            for i in range(length):
+                yield _split_block_params(
+                    jax.tree.map(lambda a, i=i: a[i], seg_params))[0]
         else:
-            blocks.append(seg_params)
-    return [_split_block_params(bp)[0] for bp in blocks]
+            yield _split_block_params(seg_params)[0]
 
 
 @dataclasses.dataclass
@@ -105,19 +113,21 @@ class WeightResidencyManager:
 
     def __init__(self, models: Dict[str, Tuple[Any, ModelConfig]],
                  arena_slots: int, *, reuse: bool = True):
-        store_input: List[Tuple[str, List[np.ndarray]]] = []
         offset_groups: List[int] = []
         self.layer_ids: Dict[str, List[int]] = {}
         self.model_of: List[str] = []
-        for name, (params, cfg) in models.items():
-            per_layer = model_layer_tensors(params, cfg)
-            ids = []
-            for i, tensors in enumerate(per_layer):
-                ids.append(len(store_input))
-                store_input.append((f"{name}/L{i}", tensors))
-                offset_groups.append(i)   # align tenants layer-by-layer
-                self.model_of.append(name)
-            self.layer_ids[name] = ids
+        for name, (_, cfg) in models.items():
+            n = cfg.n_layers
+            self.layer_ids[name] = list(range(len(offset_groups),
+                                              len(offset_groups) + n))
+            offset_groups.extend(range(n))   # align tenants layer-by-layer
+            self.model_of.extend([name] * n)
+        # layers stream into the store one at a time (quantized as they
+        # arrive), so the host never holds every tenant's float weights
+        store_input = ((f"{name}/L{i}", tensors)
+                       for name, (params, cfg) in models.items()
+                       for i, tensors in enumerate(
+                           model_layer_tensors(params, cfg)))
         # layer-group label per store layer (the §V-C offset group): the
         # wear map's slot×group dimension keys on it
         self.group_of: List[int] = offset_groups
@@ -192,17 +202,27 @@ class WeightResidencyManager:
         got = self._cost_cache.get(key)
         if got is None:
             raw = self.store.layers[layer].codes.size
-            flips, pulses = self.store.install_flips(
+            wire, skip, flips, pulses = self.store.pair_cost(
                 occupant, layer, skip_equal=self.reuse)
-            if not self.reuse:
-                got = (raw, 0.0, flips, pulses)
-            else:
-                wire, skip = self.store.install_cost(occupant, layer)
-                if wire >= raw:
-                    wire, skip = raw, 0.0
-                got = (wire, skip, flips, pulses)
+            if not self.reuse or wire >= raw:
+                wire, skip = raw, 0.0
+            got = (wire, skip, flips, pulses)
             self._cost_cache[key] = got
         return got
+
+    def prefetch_costs(self, layers: Iterable[int],
+                       slots: Iterable[int]) -> None:
+        """Fill the pair-cost memo for every (slot occupant, layer) pair a
+        greedy victim scan is about to rank, on a thread pool: each pair is
+        a pass over two layers' codes (numpy releases the GIL), and a
+        tenant switch at full width ranks thousands of pairs."""
+        todo = {(self.slots[s], l) for l in layers for s in slots}
+        todo -= self._cost_cache.keys()
+        if len(todo) < 2:
+            return
+        workers = min(len(os.sched_getaffinity(0)), _MAX_COST_WORKERS)
+        with ThreadPoolExecutor(workers) as pool:
+            list(pool.map(lambda key: self._cost(*key), todo))
 
     def _victim_key(self, slot: int, wire: int) -> Tuple[int, int]:
         """Victim-ranking key blending delta cost with slot wear.  With
@@ -297,6 +317,7 @@ class WeightResidencyManager:
                 f"weight arena too small: need {len(missing)} slots for "
                 f"{model}, only {len(candidates)} evictable")
 
+        self.prefetch_costs(missing, candidates)
         wire_total = 0
         while missing:
             best = None
@@ -398,9 +419,10 @@ class InstallPipeline:
 
     def _pick(self, pinned: Set[str]) -> Optional[Tuple[int, int, int]]:
         best = None
-        for slot in range(self.res.arena_slots):
-            if not self._evictable(slot, pinned):
-                continue
+        slots = [s for s in range(self.res.arena_slots)
+                 if self._evictable(s, pinned)]
+        self.res.prefetch_costs(self._missing, slots)
+        for slot in slots:
             for layer in self._missing:
                 wire = self.res._cost(self.res.slots[slot], layer)[0]
                 key = (*self.res._victim_key(slot, wire), layer,

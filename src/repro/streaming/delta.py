@@ -13,7 +13,7 @@ equal MSB cells across layers and therefore the skip ratio.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence, Tuple
+from typing import Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -25,6 +25,58 @@ def _cells(codes: np.ndarray) -> np.ndarray:
     c = codes.astype(np.int16).reshape(-1, 1)
     shifts = np.arange(CELLS_PER_WEIGHT) * 2
     return (c >> shifts) & 0x3
+
+
+# Counting cell deltas byte pair by byte pair: every (old, new) code pair
+# contributes a fixed multiset of per-cell level deltas, so one histogram of
+# the joint byte pairs gives the same counts as expanding every code into
+# its cells, in one pass over the codes.
+_CHUNK = 1 << 20
+
+
+def _pair_deltas() -> np.ndarray:
+    """(65536, 7) table: row old*256 + new counts the 2-bit cells of that
+    byte pair whose level changes by each delta in [-3, 3] (column
+    delta + 3)."""
+    cells = _cells(np.arange(256, dtype=np.uint8))
+    d = (cells[None, :, :] - cells[:, None, :] + 3).reshape(65536, -1)
+    table = np.zeros((65536, 7), np.int64)
+    for c in range(d.shape[1]):
+        np.add.at(table, (np.arange(65536), d[:, c]), 1)
+    return table
+
+
+_PAIR_DELTAS = _pair_deltas()
+_ABS_DELTA = np.abs(np.arange(7) - 3)
+
+
+def cell_delta_hist(old: Optional[np.ndarray], new: np.ndarray) -> np.ndarray:
+    """Histogram (7,) of per-cell level deltas new - old (bin delta + 3)
+    over equal-length code arrays; old None = erased (every cell at level
+    0).  Equal to bincounting `_cells(new) - _cells(old)`."""
+    joint = np.zeros(65536, np.int64)
+    for s in range(0, new.size, _CHUNK):
+        pair = new[s:s + _CHUNK]
+        if old is not None:
+            pair = (old[s:s + _CHUNK].astype(np.uint16) << 8) | pair
+        joint += np.bincount(pair, minlength=65536)
+    return joint @ _PAIR_DELTAS
+
+
+def _flips_from_hist(hist: np.ndarray, skip_equal: bool) -> Tuple[int, int]:
+    if skip_equal:
+        return int(hist.sum() - hist[3]), int(hist @ _ABS_DELTA)
+    return int(hist.sum()), int(hist @ np.maximum(_ABS_DELTA, 1))
+
+
+def _wire_from_hist(hist: np.ndarray) -> Tuple[int, float]:
+    counts = hist.astype(np.float64)
+    n = int(hist.sum())
+    probs = counts[counts > 0] / n
+    entropy_bits = float(-(probs * np.log2(probs)).sum())
+    payload = int(np.ceil(n * entropy_bits / 8.0)) + 16
+    skip = float(counts[3] / n)  # Δ == 0
+    return payload, skip
 
 
 def flip_counts(old: Optional[np.ndarray], new: np.ndarray, *,
@@ -39,17 +91,11 @@ def flip_counts(old: Optional[np.ndarray], new: np.ndarray, *,
     the paper's Fig. 13 writing-activity metric).  skip_equal=False is the
     baseline programmer: every cell is rewritten, an unchanged level still
     costing its one write/verify pulse."""
-    cn = _cells(new)
-    if old is None or old.size == 0:
-        d = cn
-    else:
-        n = min(old.size, new.size)
-        d = cn.copy()
-        d[:n] -= _cells(old[:n])
-    d = np.abs(d)
-    if skip_equal:
-        return int(np.count_nonzero(d)), int(d.sum())
-    return int(d.size), int(np.maximum(d, 1).sum())
+    n = 0 if old is None else min(old.size, new.size)
+    hist = cell_delta_hist(None, new[n:])
+    if n:
+        hist = hist + cell_delta_hist(old[:n], new[:n])
+    return _flips_from_hist(hist, skip_equal)
 
 
 def delta_bytes(old: np.ndarray, new: np.ndarray) -> Tuple[int, float]:
@@ -60,17 +106,8 @@ def delta_bytes(old: np.ndarray, new: np.ndarray) -> Tuple[int, float]:
     frequency table); mean-centering (§V-C) concentrates Δ at 0, which is
     exactly what shrinks H — the information-theoretic counterpart of
     skipped ReRAM pulses.  RLE framing was measured strictly worse on
-    fragmented skip patterns (isolated equal cells cost a run token each);
-    see EXPERIMENTS.md §Perf iteration 3."""
-    co, cn = _cells(old), _cells(new)
-    delta = (cn - co).reshape(-1)
-    n = delta.size
-    counts = np.bincount(delta + 3, minlength=7).astype(np.float64)
-    probs = counts[counts > 0] / n
-    entropy_bits = float(-(probs * np.log2(probs)).sum())
-    payload = int(np.ceil(n * entropy_bits / 8.0)) + 16
-    skip = float(counts[3] / n)  # Δ == 0
-    return payload, skip
+    fragmented skip patterns (isolated equal cells cost a run token each)."""
+    return _wire_from_hist(cell_delta_hist(old, new))
 
 
 @dataclasses.dataclass
@@ -107,7 +144,7 @@ class QuantizedStore:
     every cell and destroying the cross-tenant reuse it exists to enable.
     """
 
-    def __init__(self, layers: Sequence[Tuple[str, List[np.ndarray]]],
+    def __init__(self, layers: Iterable[Tuple[str, List[np.ndarray]]],
                  reuse: bool = True, max_clip_rate: float = 4e-3,
                  offset_groups: Optional[Sequence[object]] = None):
         # Quantize each tensor per-tensor (uint8 affine).
@@ -179,6 +216,28 @@ class QuantizedStore:
                     # Eq. 7: compensate through the zero point.
                     lw.zero_points = [zp + off for zp in lw.zero_points]
 
+    def pair_cost(self, resident: Optional[int], incoming: int, *,
+                  skip_equal: bool = True) -> Tuple[int, float, int, int]:
+        """What putting layer `incoming` into a slot holding `resident`
+        (None = cold slot) costs, from one pass over the codes: (wire bytes,
+        skip ratio) as `install_cost`, and (cells programmed, programming
+        pulses) the DEVICE spends, as `flip_counts`.  The device side is
+        independent of the wire encoding: even when the raw code stream
+        ships (delta entropy exceeded 2 bits/cell), a skip_equal programmer
+        still read-verifies and skips equal 2-bit planes.  skip_equal=False
+        models the no-reuse baseline that rewrites every cell."""
+        new = self.layers[incoming].codes
+        old = None if resident is None else self.layers[resident].codes
+        n = 0 if old is None else min(old.size, new.size)
+        tail = cell_delta_hist(None, new[n:])
+        if n == 0:
+            return (new.size, 0.0,
+                    *_flips_from_hist(tail, skip_equal))
+        head = cell_delta_hist(old[:n], new[:n])
+        wire, skip = _wire_from_hist(head)
+        return (wire + (new.size - n), skip,
+                *_flips_from_hist(head + tail, skip_equal))
+
     def install_cost(self, resident: Optional[int], incoming: int
                      ) -> Tuple[int, float]:
         """(bytes-on-wire, skip ratio) to put layer `incoming` into a slot
@@ -192,17 +251,3 @@ class QuantizedStore:
             return new.size, 0.0
         b, skip = delta_bytes(old[:n], new[:n])
         return b + (new.size - n), skip
-
-    def install_flips(self, resident: Optional[int], incoming: int, *,
-                      skip_equal: bool = True) -> Tuple[int, int]:
-        """(cells programmed, programming pulses) the DEVICE spends putting
-        layer `incoming` into a slot holding `resident` (None = cold slot,
-        programmed from erased).  This is the physical-write counterpart of
-        `install_cost` and is independent of the wire encoding: even when
-        the raw code stream ships (delta entropy exceeded 2 bits/cell), a
-        skip_equal programmer still read-verifies and skips equal 2-bit
-        planes.  skip_equal=False models the no-reuse baseline that
-        rewrites every cell."""
-        new = self.layers[incoming].codes
-        old = None if resident is None else self.layers[resident].codes
-        return flip_counts(old, new, skip_equal=skip_equal)

@@ -27,8 +27,8 @@ PAGE = 4
 def _ops_inputs(H=4, Hkv=2, D=8, P=6, T=3, B=2):
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(B, H, D)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(P, PAGE, Hkv, D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(P, PAGE, Hkv, D)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)).astype(np.float32))
     tables = jnp.asarray(rng.integers(0, P, (B, T)), jnp.int32)
     pos = jnp.asarray([3, 5], jnp.int32)
     return q, kp, vp, tables, pos
@@ -77,7 +77,7 @@ def _run_engine(backend, fuse, *, int8=False):
                      kv_layout="paged", page_size=PAGE, n_pages=10,
                      prefix_cache=True, kernel_backend=backend)],
         sched=SchedulerConfig(max_prefill_per_step=2),
-        fuse_sampling=fuse, kernel_interpret=True)
+        fuse_sampling=fuse)
     rng = np.random.default_rng(5)
     shared = rng.integers(1, cfg.vocab, 10).tolist()   # 2.5 pages
     # r1 runs two steps, then an identical prompt arrives mid-decode:

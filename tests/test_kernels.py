@@ -83,13 +83,13 @@ def test_paged_attention_kernel_sweep(b, h, hkv, d, pool, page, t, dtype):
     including rows whose position sits mid-page (masked tail)."""
     rng = np.random.default_rng(b * pool + page)
     q = jnp.asarray(rng.standard_normal((b, h, d)), dtype)
-    kp = jnp.asarray(rng.standard_normal((pool, page, hkv, d)), dtype)
-    vp = jnp.asarray(rng.standard_normal((pool, page, hkv, d)), dtype)
+    kp = jnp.asarray(rng.standard_normal((pool, page, hkv * d)), dtype)
+    vp = jnp.asarray(rng.standard_normal((pool, page, hkv * d)), dtype)
     tables = jnp.asarray(
         np.stack([rng.choice(pool, t, replace=False) for _ in range(b)]),
         jnp.int32)
     pos = jnp.asarray(rng.integers(0, t * page, b), jnp.int32)
-    o = paged_attention(q, kp, vp, tables, pos)
+    o = paged_attention(q, kp, vp, tables, pos, interpret=True)
     r = paged_attention_ref(q, kp, vp, tables, pos)
     tol = 2e-2 if dtype == jnp.bfloat16 else 3e-4
     np.testing.assert_allclose(np.asarray(o, np.float32),
@@ -102,19 +102,19 @@ def test_paged_attention_masks_stale_pages():
     rng = np.random.default_rng(7)
     b, h, hkv, d, pool, page, t = 2, 4, 2, 16, 10, 4, 5
     q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    kp = np.asarray(rng.standard_normal((pool, page, hkv, d)), np.float32)
-    vp = np.asarray(rng.standard_normal((pool, page, hkv, d)), np.float32)
+    kp = np.asarray(rng.standard_normal((pool, page, hkv * d)), np.float32)
+    vp = np.asarray(rng.standard_normal((pool, page, hkv * d)), np.float32)
     # disjoint tables: a page stale for one row must not be live in another
     tables = rng.permutation(pool).reshape(b, t)
     pos = np.asarray([5, 9])
     base = paged_attention(q, jnp.asarray(kp), jnp.asarray(vp),
                            jnp.asarray(tables, np.int32),
-                           jnp.asarray(pos, np.int32))
+                           jnp.asarray(pos, np.int32), interpret=True)
     for row in range(b):
         for blk in range(pos[row] // page + 1, t):
-            kp[tables[row, blk]] = 1e4 * rng.standard_normal((page, hkv, d))
+            kp[tables[row, blk]] = 1e4 * rng.standard_normal((page, hkv * d))
             vp[tables[row, blk]] = 1e4
     out = paged_attention(q, jnp.asarray(kp), jnp.asarray(vp),
                           jnp.asarray(tables, np.int32),
-                          jnp.asarray(pos, np.int32))
+                          jnp.asarray(pos, np.int32), interpret=True)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(out))

@@ -173,6 +173,13 @@ def test_allocator_retention_manual_trials():
         _random_trial(200 + seed, retain=False)
 
 
+@pytest.mark.parametrize("cap", [0, 1])
+def test_cache_cap_holds_once_children_drop(cap):
+    """Seed 3 retains a page while it still has live children, so the cap
+    cannot evict it then; the cap must be enforced again when they drop."""
+    _random_trial(3, max_cached=cap)
+
+
 def test_allocator_retention_property_random_ops():
     """Hypothesis sweep over the same op machine."""
     pytest.importorskip("hypothesis")

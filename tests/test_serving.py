@@ -9,10 +9,11 @@ import pytest
 
 from repro.configs import get_config
 from repro.launch.steps import cached_prefill_step, cached_serve_step
-from repro.nn.model import init_params
+from repro.nn.model import init_params, serving_params
 from repro.serving import (EngineModel, KVArena, SchedulerConfig,
                            ServingEngine, StepScheduler, WeightResidencyManager)
 from repro.serving.request import Request, RequestStatus
+from repro.serving.variants import perturbed_variant
 
 MAX_SEQ = 32
 CFG = get_config("gemma-7b", smoke=True)
@@ -65,6 +66,35 @@ def test_engine_matches_sequential_decode_token_for_token():
         params = PARAMS_A if r.model == "a" else PARAMS_B
         ref = sequential_tokens(params, CFG, list(r.prompt), r.max_new_tokens)
         assert r.generated == ref, f"rid {r.rid} diverged from sequential"
+
+
+@pytest.mark.parametrize("kv_layout", ["slot", "paged"])
+def test_bf16_tenant_params_match_float32_tokens(kv_layout):
+    """Serving-held weights (matmul leaves bf16, placed on the device once)
+    give exactly the float32 tenants' tokens — the forward pass casts
+    every matmul weight to bf16 where it is used — and no tenant keeps a
+    host (numpy) leaf, which every step would copy to the device."""
+    host_b = jax.tree.map(np.asarray, perturbed_variant(PARAMS_A))
+    runs = {}
+    for tag, (pa, pb) in {
+            "f32": (PARAMS_A, host_b),
+            "bf16": (serving_params(PARAMS_A), serving_params(host_b)),
+    }.items():
+        eng = ServingEngine(
+            [EngineModel(n, p, CFG, kv_slots=3, max_seq=MAX_SEQ,
+                         kv_layout=kv_layout, page_size=4)
+             for n, p in (("a", pa), ("b", pb))],
+            sched=SchedulerConfig(max_prefill_per_step=2),
+            weight_arena_slots=CFG.n_layers + 1)
+        for m in eng.models.values():
+            leaves = jax.tree.leaves(m.params)
+            assert all(isinstance(l, jax.Array) for l in leaves), m.name
+            assert any(l.dtype == jnp.bfloat16 for l in leaves) == (
+                tag == "bf16")
+        reqs = submit_mixed(eng, 6)
+        eng.run()
+        runs[tag] = [r.generated for r in reqs]
+    assert runs["bf16"] == runs["f32"]
 
 
 def test_requests_join_and_leave_between_steps():

@@ -66,3 +66,24 @@ def test_dryrun_smoke_cell(tmp_path):
     assert roof["dominant"] in ("compute", "memory", "collective")
     assert roof["flops_per_device"] > 0
     assert cell["memory_analysis"]["temp_size_in_bytes"] is not None
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir_rule(env_dir, tmp_path):
+    """Entry points keep JAX's persistent compile cache where
+    JAX_COMPILATION_CACHE_DIR says, setting no other directory; without it,
+    at one fixed path inside the checkout."""
+    env = dict(os.environ, PYTHONPATH="src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    code = ("import jax; from repro.launch.compile_cache import "
+            "setup_compile_cache; d = setup_compile_cache(); "
+            "print(d); print(jax.config.jax_compilation_cache_dir)")
+    r = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    chosen, configured = r.stdout.split()
+    want = (str(tmp_path / env_dir) if env_dir is not None else
+            os.path.realpath(os.path.join(ROOT, ".jax_cache")))
+    assert chosen == configured == want

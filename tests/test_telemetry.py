@@ -70,9 +70,19 @@ def generated_by_rid(eng):
 
 
 # ------------------------------------------------------- quantile maths
-def _quantile_invariants(samples, window):
+# P2 guarantees exactness below 5 samples and an estimate inside the
+# sample range after that; closeness to the true quantile comes only once
+# its markers have converged.  A property sweep over arbitrary streams
+# finds 20-sample streams whose median estimate sits a third of the range
+# away, and none from 50 samples on; 100 leaves a margin.
+P2_CONVERGED = 100
+
+
+def _quantile_invariants(samples, window, accurate_from=5):
     """Shared property body: windowed quantiles are exact `np.percentile`
-    over the tail; the lifetime P2 estimate stays within tolerance."""
+    over the tail; the lifetime P2 estimate is exact below 5 samples,
+    inside [min, max] from there, and within tolerance of the truth from
+    `accurate_from` samples on."""
     stat = StreamStat(window=window)
     for x in samples:
         stat.observe(float(x))
@@ -84,7 +94,8 @@ def _quantile_invariants(samples, window):
     assert snap["p50"] == pytest.approx(np.percentile(tail, 50))
     assert snap["p95"] == pytest.approx(np.percentile(tail, 95))
     # P2 is exact below 5 samples (it keeps them all); for longer
-    # streams it must stay inside the sample range and near the truth
+    # streams it must stay inside the sample range, and near the truth
+    # once converged
     full = np.asarray(samples, dtype=float)
     if len(samples) < 5:
         assert snap["stream_p50"] == pytest.approx(np.percentile(full, 50))
@@ -94,8 +105,9 @@ def _quantile_invariants(samples, window):
         span = max(hi - lo, 1e-12)
         assert lo <= snap["stream_p50"] <= hi
         assert lo <= snap["stream_p95"] <= hi
-        assert abs(snap["stream_p50"] - np.percentile(full, 50)) \
-            <= 0.25 * span
+        if len(samples) >= accurate_from:
+            assert abs(snap["stream_p50"] - np.percentile(full, 50)) \
+                <= 0.25 * span
     assert stat.p50() == snap["p50"]
 
 
@@ -142,7 +154,7 @@ def test_p2_converges_on_large_stream():
 
 def test_windowed_quantiles_property():
     """Hypothesis sweep of `_quantile_invariants` over random streams and
-    window sizes."""
+    window sizes, the P2 accuracy bound held from P2_CONVERGED samples."""
     pytest.importorskip("hypothesis")
     from hypothesis import given, settings, strategies as st
 
@@ -151,7 +163,7 @@ def test_windowed_quantiles_property():
                        min_size=1, max_size=200),
            window=st.integers(1, 64))
     def prop(xs, window):
-        _quantile_invariants(xs, window)
+        _quantile_invariants(xs, window, accurate_from=P2_CONVERGED)
 
     prop()
 

@@ -18,7 +18,7 @@ from repro.serving import (EngineModel, SchedulerConfig, ServingEngine,
                            Tracer, VirtualClock, WearPlane, drive_simulated,
                            gini_coefficient)
 from repro.serving.variants import perturbed_variant
-from repro.streaming.delta import _cells, flip_counts
+from repro.streaming.delta import _cells, cell_delta_hist, flip_counts
 
 MAX_SEQ = 48
 CFG = get_config("gemma-7b", smoke=True)
@@ -60,6 +60,22 @@ def make_engine(*, reuse=True, paged=False, prefix_cache=False,
 
 
 # ------------------------------------------------------- flip semantics
+@pytest.mark.parametrize("n", [1, 1000, (1 << 20) + 3])   # last: 2 chunks
+def test_cell_delta_hist_matches_cell_expansion(n):
+    """The joint byte-pair histogram counts exactly the per-cell level
+    deltas that expanding every code into its 2-bit cells gives — the
+    install accounting's integers do not depend on which one computes
+    them."""
+    rng = np.random.default_rng(n)
+    old = rng.integers(0, 256, n).astype(np.uint8)
+    new = np.clip(old.astype(int) + rng.integers(-3, 4, n), 0,
+                  255).astype(np.uint8)
+    for o, w in ((old, new), (None, new), (new, old)):
+        d = _cells(w) - (0 if o is None else _cells(o))
+        want = np.bincount(d.reshape(-1) + 3, minlength=7)
+        np.testing.assert_array_equal(cell_delta_hist(o, w), want)
+
+
 def test_flip_counts_semantics():
     rng = np.random.default_rng(0)
     old = rng.integers(0, 256, 64).astype(np.uint8)

@@ -1,0 +1,61 @@
+"""BENCHMARK.json is found by name all the way down: every cell's
+configuration and traffic file exists, every metric has its reader, and
+the entries keep to the keys and names the benchmark's contract allows."""
+import importlib
+import json
+import re
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+
+
+def test_top_level_keys():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert BENCH["command"] == ["python3", "bench/run.py"]
+    assert BENCH["paths"] == ["bench"]
+    assert 1 <= BENCH["run_seconds"] <= 51
+
+
+def test_cells_find_their_files():
+    configs = {c["name"]: c for c in BENCH["configs"]}
+    for c in BENCH["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        conf = json.loads((ROOT / c["file"]).read_text())
+        assert c["file"] == f"bench/configs/{c['name']}.json"
+        assert sorted(c["reduced"]) == sorted(conf["reduced"])
+        importlib.import_module(f"bench.references.{conf['reference']}")
+    for w in BENCH["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert w["name"] == f"{w['config']}.{w['traffic']}"
+        assert w["config"] in configs and w["chips"] == 1
+        assert (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").exists()
+        assert 1 <= len(w["why"]) <= 200
+
+
+def test_every_metric_has_a_reader_and_valid_names():
+    cells = {w["name"] for w in BENCH["workloads"]}
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+        assert set(m.get("workloads", cells)) <= cells
+        mod = importlib.import_module(f"bench.metrics.{m['name']}")
+        assert callable(mod.read)
+    for m in BENCH["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in BENCH["per_layer"]:
+        assert m["moves"] in e2e
+        # each listed cell reports the metric this one moves
+        moved = e2e[m["moves"]]
+        assert set(m["workloads"]) <= set(moved.get("workloads", cells))
+    for cell in cells:
+        reported = [m for m in BENCH["end_to_end"]
+                    if cell in m.get("workloads", cells)]
+        assert len(reported) >= 2
+        assert any(cell in m["workloads"] for m in BENCH["per_layer"])

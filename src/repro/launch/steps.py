@@ -51,9 +51,17 @@ def make_prefill_step(cfg: ModelConfig, cache_len: int):
     return prefill_step
 
 
+# Root of the decode programs' named scopes; their parts are embed,
+# attn_proj, kv_pool, paged_attention, mlp, lm_head (nn.model,
+# nn.transformer, nn.attention) and sample (below).  Scopes are metadata
+# on the HLO ops: the compiled program is unchanged.
+DECODE_SCOPE = "decode_step"
+
+
 def make_serve_step(cfg: ModelConfig):
     def serve_step(params, token, caches, pos):
-        return decode_step(params, token, caches, pos, cfg)
+        with jax.named_scope(DECODE_SCOPE):
+            return decode_step(params, token, caches, pos, cfg)
     return serve_step
 
 
@@ -185,10 +193,11 @@ def cached_stage_quantize(cfg: ModelConfig, staging_len: int):
 def make_paged_serve_step(cfg: ModelConfig, kernel_backend: str = "xla",
                           interpret: bool = False):
     def paged_serve_step(params, token, caches, pos, page_table):
-        return decode_step(params, token, caches, pos, cfg,
-                           page_table=page_table,
-                           kernel_backend=kernel_backend,
-                           kernel_interpret=interpret)
+        with jax.named_scope(DECODE_SCOPE):
+            return decode_step(params, token, caches, pos, cfg,
+                               page_table=page_table,
+                               kernel_backend=kernel_backend,
+                               kernel_interpret=interpret)
     return paged_serve_step
 
 
@@ -217,12 +226,13 @@ def cached_fused_paged_serve_step(cfg: ModelConfig,
 
     def fused_paged_serve_step(params, token, caches, pos, page_table,
                                temps, top_ks, keys, steps):
-        logits, new_caches = decode_step(params, token, caches, pos, cfg,
-                                         page_table=page_table,
-                                         kernel_backend=kernel_backend,
-                                         kernel_interpret=interpret)
-        toks = sample_tokens(logits, cfg.vocab, temperatures=temps,
-                             top_ks=top_ks, keys=keys, steps=steps)
+        with jax.named_scope(DECODE_SCOPE):
+            logits, new_caches = decode_step(
+                params, token, caches, pos, cfg, page_table=page_table,
+                kernel_backend=kernel_backend, kernel_interpret=interpret)
+            with jax.named_scope("sample"):
+                toks = sample_tokens(logits, cfg.vocab, temperatures=temps,
+                                     top_ks=top_ks, keys=keys, steps=steps)
         return toks, new_caches
 
     return jax.jit(fused_paged_serve_step, donate_argnums=(2,))

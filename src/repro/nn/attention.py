@@ -13,6 +13,7 @@ All paths accumulate in fp32 and share a single mask rule:
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -310,13 +311,14 @@ def attention(
     `repro.kernels.interpret_mode()`) — both are trace-time constants.
     """
     B, S, _ = x.shape
-    if positions is None:
-        positions = jnp.arange(S)[None, :] if cache_pos is None else (
-            cache_pos[:, None] if cache_pos.ndim else
-            jnp.full((B, 1), cache_pos)
-        )
-        if positions.shape[0] == 1 and B > 1:
-            positions = jnp.broadcast_to(positions, (B, S))
+    with jax.named_scope("attn_proj"):
+        if positions is None:
+            positions = jnp.arange(S)[None, :] if cache_pos is None else (
+                cache_pos[:, None] if cache_pos.ndim else
+                jnp.full((B, 1), cache_pos)
+            )
+            if positions.shape[0] == 1 and B > 1:
+                positions = jnp.broadcast_to(positions, (B, S))
 
     if cfg.attn_type == "mla":
         return _mla_attention(params, x, cfg, positions=positions,
@@ -324,7 +326,8 @@ def attention(
                               cache_pos=cache_pos, make_cache=make_cache,
                               cache_len=cache_len, page_table=page_table)
 
-    q, k, v = _gqa_qkv(params, x, cfg, positions)
+    with jax.named_scope("attn_proj"):
+        q, k, v = _gqa_qkv(params, x, cfg, positions)
     new_cache = None
     o = None
 
@@ -342,23 +345,26 @@ def attention(
                 # the XLA path below.  int8 pools are dequantized
                 # elementwise first: identical values to the XLA path's
                 # gather-then-dequant.
-                new_cache = _paged_scatter_gqa(cache, k, v, cfg,
-                                               cache_pos, page_table)
                 H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-                if cfg.kv_cache_dtype == "int8":
-                    kp = _pool_dequant(new_cache["k"], new_cache["k_scale"],
-                                       k.dtype)
-                    vp = _pool_dequant(new_cache["v"], new_cache["v_scale"],
-                                       v.dtype)
-                else:
-                    kp, vp = new_cache["k"], new_cache["v"]
-                o = _pallas_paged(q[:, 0].reshape(B, H, D), kp, vp,
-                                  page_table, cache_pos,
-                                  interpret=kernel_interpret)
-                o = o.reshape(B, 1, Hkv, H // Hkv, D)
+                with jax.named_scope("kv_pool"):
+                    new_cache = _paged_scatter_gqa(cache, k, v, cfg,
+                                                   cache_pos, page_table)
+                    if cfg.kv_cache_dtype == "int8":
+                        kp = _pool_dequant(new_cache["k"],
+                                           new_cache["k_scale"], k.dtype)
+                        vp = _pool_dequant(new_cache["v"],
+                                           new_cache["v_scale"], v.dtype)
+                    else:
+                        kp, vp = new_cache["k"], new_cache["v"]
+                with jax.named_scope("paged_attention"):
+                    o = _pallas_paged(q[:, 0].reshape(B, H, D), kp, vp,
+                                      page_table, cache_pos,
+                                      interpret=kernel_interpret)
+                    o = o.reshape(B, 1, Hkv, H // Hkv, D)
             else:
-                kc, vc, new_cache = _paged_append_gqa(cache, k, v, cfg,
-                                                      cache_pos, page_table)
+                with jax.named_scope("kv_pool"):
+                    kc, vc, new_cache = _paged_append_gqa(
+                        cache, k, v, cfg, cache_pos, page_table)
                 Sc = kc.shape[1]
                 kpos = jnp.arange(Sc)[None, :]
                 cp = cache_pos[:, None]
@@ -428,14 +434,18 @@ def attention(
                     valid = kpos <= cp
                 valid = valid[:, None, :]                 # (B|1, 1, Sc)
         if o is None:
-            scale = 1.0 / math.sqrt(cfg.head_dim)
-            s = jnp.einsum("bqhgd,bkhd->bhgqk", q.astype(jnp.float32),
-                           kc.astype(jnp.float32)) * scale
-            if cfg.logit_softcap > 0:
-                s = cfg.logit_softcap * jnp.tanh(s / cfg.logit_softcap)
-            s = jnp.where(valid[:, None, None], s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhgqk,bkhd->bqhgd", p, vc.astype(jnp.float32))
+            # the XLA path's attend (over the gathered pages when paged)
+            with (jax.named_scope("paged_attention") if page_table is not None
+                  else contextlib.nullcontext()):
+                scale = 1.0 / math.sqrt(cfg.head_dim)
+                s = jnp.einsum("bqhgd,bkhd->bhgqk", q.astype(jnp.float32),
+                               kc.astype(jnp.float32)) * scale
+                if cfg.logit_softcap > 0:
+                    s = cfg.logit_softcap * jnp.tanh(s / cfg.logit_softcap)
+                s = jnp.where(valid[:, None, None], s, NEG_INF)
+                p = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("bhgqk,bkhd->bqhgd", p,
+                               vc.astype(jnp.float32))
     else:
         if S <= 1024:
             mask = _mask(positions, positions, causal=cfg.causal,
@@ -486,8 +496,9 @@ def attention(
             if int8:
                 new_cache.update({"k_scale": ksc, "v_scale": vsc})
 
-    o = o.astype(x.dtype).reshape(B, S, cfg.q_dim)
-    y = jnp.einsum("btq,qd->btd", o, params["w_o"].astype(x.dtype))
+    with jax.named_scope("attn_proj"):
+        o = o.astype(x.dtype).reshape(B, S, cfg.q_dim)
+        y = jnp.einsum("btq,qd->btd", o, params["w_o"].astype(x.dtype))
     return y, new_cache
 
 

@@ -102,16 +102,18 @@ def forward(
 ) -> Tuple[jax.Array, Optional[Any], jax.Array]:
     """Returns (logits, new_caches, aux_loss).  ``last_only`` restricts the
     unembed to the final position (prefill/decode)."""
-    x, prefix_len = _inputs_to_x(params, batch, cfg)
+    with jax.named_scope("embed"):
+        x, prefix_len = _inputs_to_x(params, batch, cfg)
     x, new_caches, aux = apply_stack(
         params["stack"], x, cfg, prefix_len=prefix_len, caches=caches,
         cache_pos=cache_pos, make_cache=make_cache, cache_len=cache_len,
         page_table=page_table,
         kernel_backend=kernel_backend, kernel_interpret=kernel_interpret)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    if last_only:
-        x = x[:, -1:]
-    logits = unembed(params["embedding"], x, cfg)
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        if last_only:
+            x = x[:, -1:]
+        logits = unembed(params["embedding"], x, cfg)
     return logits, new_caches, aux
 
 

@@ -125,7 +125,8 @@ def apply_block(
     state past it; attention masks make it irrelevant there)."""
     kind = layer_kind(cfg, i)
     aux = jnp.zeros((), jnp.float32)
-    h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
+    with jax.named_scope("attn_proj"):
+        h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
 
     if kind in ("mlstm", "slstm"):
         fn = mlstm if kind == "mlstm" else slstm
@@ -158,19 +159,21 @@ def apply_block(
         if new_attn_cache is not None:
             new_cache = {"attn": new_attn_cache}
 
-    x = x + y
-    x = shard(x, "batch", "sp", None)
-    h2 = rmsnorm(bp["norm2"], x, cfg.norm_eps)
-    if "moe" in bp:
-        # multi-token chunked prefill takes the batch routing path (same
-        # numerics as the monolithic prefill); only true one-token steps
-        # use the replicated-token decode strategy
-        y2, aux = moe(bp["moe"], h2, cfg,
-                      decode=(cache is not None and x.shape[1] == 1))
-    else:
-        y2 = mlp(bp["mlp"], h2, cfg.act)
-    x = x + y2
-    x = shard(x, "batch", "sp", None)
+    with jax.named_scope("attn_proj"):
+        x = x + y
+        x = shard(x, "batch", "sp", None)
+    with jax.named_scope("mlp"):
+        h2 = rmsnorm(bp["norm2"], x, cfg.norm_eps)
+        if "moe" in bp:
+            # multi-token chunked prefill takes the batch routing path
+            # (same numerics as the monolithic prefill); only true
+            # one-token steps use the replicated-token decode strategy
+            y2, aux = moe(bp["moe"], h2, cfg,
+                          decode=(cache is not None and x.shape[1] == 1))
+        else:
+            y2 = mlp(bp["mlp"], h2, cfg.act)
+        x = x + y2
+        x = shard(x, "batch", "sp", None)
     return x, new_cache, aux
 
 

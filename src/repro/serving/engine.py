@@ -943,7 +943,8 @@ class ServingEngine:
             if not slots:
                 continue
             fused = self._decode_fused[name] is not None
-            temps, tks, keys, steps = self._sample_inputs(arena)
+            with self.tracer.span("inputs", tenant=name):
+                temps, tks, keys, steps = self._sample_inputs(arena)
             with self.tracer.span("decode", tenant=name, n_slots=len(slots)):
                 if paged and fused:
                     # fused step: sampling runs on device, logits never

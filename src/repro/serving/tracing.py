@@ -3,9 +3,13 @@
 The :class:`Tracer` records two kinds of structured events:
 
 * **Component spans** — per-step timings of engine phases (``schedule``,
-  ``prefill``, ``decode``, ``sample``, ``install``, ``page``, ``prefix``,
-  ``bookkeep``).  The engine drains the per-step accumulation via
-  :meth:`Tracer.step_components` and stores it on ``StepRecord.component_s``.
+  ``prefill``, ``inputs``, ``decode``, ``sample``, ``install``, ``page``,
+  ``prefix``, ``bookkeep``).  The engine drains the per-step accumulation
+  via :meth:`Tracer.step_components` and stores it on
+  ``StepRecord.component_s``.  Each span is also a
+  ``jax.profiler.TraceAnnotation`` named ``engine.<component>``, so a
+  profile taken while the engine runs shows the phases on the host line,
+  on the same clock as the device's operations.
 * **Request lifecycle spans** — one span per scheduling phase of each
   request (``queued`` → ``prefilling`` → ``running`` → ``finished`` /
   ``preempted``), driven by :meth:`Tracer.request_phase`.
@@ -45,6 +49,7 @@ TRACE_COMPONENTS: Tuple[str, ...] = (
     "schedule",
     "install",
     "prefill",
+    "inputs",
     "decode",
     "sample",
     "page",
@@ -123,22 +128,30 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    """Context manager recording one component span on exit."""
+    """Context manager recording one component span on exit, inside a
+    profiler annotation ``engine.<component>`` (timed outside the clock
+    readings, so ``component_s`` does not count the annotation)."""
 
-    __slots__ = ("_tracer", "_component", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_component", "_attrs", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", component: str, attrs: Dict[str, Any]):
         self._tracer = tracer
         self._component = component
         self._attrs = attrs
         self._t0 = 0.0
+        self._annotation = None
 
     def __enter__(self):
+        self._annotation = self._tracer._annotate("engine." + self._component)
+        self._annotation.__enter__()
         self._t0 = self._tracer._clock()
         return self
 
     def __exit__(self, *exc):
-        self._tracer._end_span(self._component, self._t0, self._attrs)
+        try:
+            self._tracer._end_span(self._component, self._t0, self._attrs)
+        finally:
+            self._annotation.__exit__(*exc)
         return False
 
 
@@ -160,7 +173,10 @@ class Tracer:
             import time
 
             clock = time.perf_counter
+        from jax.profiler import TraceAnnotation
+
         self._clock = clock
+        self._annotate = TraceAnnotation
         # Chrome trace events, in emission order.
         self.events: List[Dict[str, Any]] = []
         # Per-step component-duration accumulator (seconds), drained by

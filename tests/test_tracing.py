@@ -3,24 +3,38 @@ deterministic Chrome-trace document with balanced request lifecycles; the
 per-step component breakdown must account (exactly, under virtual time)
 for step wall time; the typed metrics registry must reproduce the legacy
 EngineMetrics quantile behaviour; and the disabled-tracer path must stay
-allocation-free so instrumentation is safe to leave in hot loops."""
+allocation-free so instrumentation is safe to leave in hot loops.  An
+enabled tracer's spans also land on the profiler's host line as
+`engine.<component>`, and the decode program's named scopes are metadata
+that leave its compiled HLO as it was."""
+import contextlib
 import json
 import math
+import re
+import sys
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.steps import cached_fused_paged_serve_step
 from repro.nn.model import init_params
 from repro.serving import (EngineMetrics, EngineModel, InstallCostModel,
                            MetricsRegistry, NULL_TRACER, NullTracer,
                            SchedulerConfig, ServingEngine, Tracer,
                            VirtualClock, WeightResidencyManager,
                            drive_simulated)
+from repro.serving.paging import init_page_pool
 from repro.serving.tracing import (_NULL_SPAN, REQUEST_PHASES,
                                    TRACE_COMPONENTS)
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+from bench import trace_reduce  # noqa: E402
 
 MAX_SEQ = 32
 CFG = get_config("gemma-7b", smoke=True)
@@ -276,3 +290,127 @@ def test_untraced_engine_records_empty_component_breakdowns():
     assert eng.metrics.steps
     for rec in eng.metrics.steps:
         assert rec.component_s == {}
+
+
+# ------------------------------------------------ on the profiler's clock
+def profiled_paged_steps(directory, tracer=None, n_steps=3):
+    """A one-tenant paged engine whose first two steps (prefill, decode
+    compile) run unprofiled; the next `n_steps` are profiled, each inside
+    a `bench.step` annotation as `bench/harness.py` makes them.
+    Returns (engine, loaded trace)."""
+    eng = ServingEngine(
+        [EngineModel("a", PARAMS_A, CFG, kv_slots=2, kv_layout="paged",
+                     page_size=4, n_pages=16)], tracer=tracer)
+    eng.submit("a", [3, 1, 4, 1, 5], max_new_tokens=n_steps + 4)
+    eng.submit("a", [2, 7, 1], max_new_tokens=n_steps + 4)
+    eng.step()
+    eng.step()
+    jax.profiler.start_trace(str(directory))
+    try:
+        for _ in range(n_steps):
+            with jax.profiler.TraceAnnotation("bench.step"):
+                eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    return eng, trace_reduce.load(trace_reduce.find_xplane(str(directory)))
+
+
+def test_engine_phases_land_on_the_profiler_host_line(tmp_path):
+    eng, tr = profiled_paged_steps(tmp_path, tracer=Tracer())
+    steps = [ev for ev in tr.host if ev.name == "bench.step"]
+    assert len(steps) == 3
+    for step, rec in zip(steps, eng.metrics.steps[-3:]):
+        inside = [ev.name for ev in tr.host
+                  if ev.name.startswith("engine.")
+                  and step.start <= ev.start and ev.end <= step.end]
+        # every component the step ran, and nothing else, nested in it
+        assert {n[len("engine."):] for n in inside} == set(rec.component_s)
+        assert {"schedule", "inputs", "decode", "sample"} <= set(
+            rec.component_s)
+    # no engine annotation falls outside a step
+    n_engine = sum(1 for ev in tr.host if ev.name.startswith("engine."))
+    assert n_engine == sum(len(r.component_s)
+                           for r in eng.metrics.steps[-3:])
+
+
+def test_null_tracer_records_no_engine_annotations(tmp_path):
+    eng, tr = profiled_paged_steps(tmp_path)
+    assert eng.tracer is NULL_TRACER
+    assert sum(1 for ev in tr.host if ev.name == "bench.step") == 3
+    assert not [ev for ev in tr.host if ev.name.startswith("engine.")]
+
+
+def test_paged_decode_step_times_its_sampler_inputs():
+    tracer = Tracer()
+    eng = ServingEngine(
+        [EngineModel("a", PARAMS_A, CFG, kv_slots=2, kv_layout="paged",
+                     page_size=4, n_pages=16)], tracer=tracer)
+    eng.submit("a", [3, 1, 4], max_new_tokens=4)
+    eng.run()
+    decoded = [r for r in eng.metrics.steps if r.n_decoded]
+    assert decoded
+    for rec in decoded:
+        assert "inputs" in rec.component_s
+        assert set(rec.component_s) <= set(TRACE_COMPONENTS)
+    assert "inputs" in TRACE_COMPONENTS
+
+
+def test_span_annotations_nest_and_close_on_error():
+    log = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, exc[0]))
+
+    tracer = Tracer()
+    tracer._annotate = Annotation
+    with pytest.raises(ValueError):
+        with tracer.span("decode"):
+            with tracer.span("page"):
+                raise ValueError("boom")
+    assert log == [("enter", "engine.decode"), ("enter", "engine.page"),
+                   ("exit", "engine.page", ValueError),
+                   ("exit", "engine.decode", ValueError)]
+    # the tracer's own record is kept as before
+    assert set(tracer.step_components()) == {"decode", "page"}
+    assert [e["name"] for e in tracer.events] == ["page", "decode"]
+
+
+def _instructions(hlo: str):
+    """The HLO's instruction lines without their metadata."""
+    return [re.sub(r",? ?metadata=\{[^}]*\}", "", line)
+            for line in hlo.splitlines()
+            if re.match(r"\s*(ROOT )?%[\w.\-]+ = ", line)]
+
+
+def test_decode_scopes_leave_the_program_unchanged(monkeypatch):
+    pcfg = CFG
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), pcfg))
+    pools = jax.eval_shape(lambda: init_page_pool(pcfg, 17, 4))
+    i32 = jax.ShapeDtypeStruct((2,), np.int32)
+    args = (params, i32, pools, i32, jax.ShapeDtypeStruct((2, 16), np.int32),
+            jax.ShapeDtypeStruct((2,), np.float32), i32,
+            jax.ShapeDtypeStruct((2, 2), np.uint32), i32)
+
+    def optimized():
+        # a fresh jit of the engine's step: traced and compiled anew
+        fn = cached_fused_paged_serve_step.__wrapped__(pcfg, "xla", False)
+        return fn.lower(*args).compile().as_text()
+
+    scoped = optimized()
+    assert 'op_name="jit(fused_paged_serve_step)/decode_step/' in scoped
+    names = set(re.findall(r'op_name="[^"]*/decode_step/([^"]*)"', scoped))
+    parts = {p for n in names for p in n.split("/")}
+    assert {"embed", "attn_proj", "kv_pool", "paged_attention", "mlp",
+            "lm_head", "sample"} <= parts
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = optimized()
+    assert "/decode_step/" not in plain
+    assert _instructions(scoped) == _instructions(plain)

@@ -165,8 +165,8 @@ def run(argv: list, *, n_requests: int = N_REQUESTS,
     vp = jax.random.normal(ks[2], (n_pages, page, Hkv * D), jnp.bfloat16)
     tab = jax.random.randint(ks[3], (B, T), 1, n_pages, jnp.int32)
     kpos = jax.random.randint(ks[4], (B,), 0, T * page, jnp.int32)
-    got = np.asarray(jax.jit(paged_attention)(q, kp, vp, tab, kpos),
-                     np.float32)
+    got = np.asarray(jax.jit(paged_attention)(q, kp[None], vp[None], tab,
+                                              kpos, 0), np.float32)
     want = np.asarray(jax.jit(paged_attention_ref)(q, kp, vp, tab, kpos),
                       np.float32)
     excess = np.abs(got - want) - KERNEL_TOL * (1.0 + np.abs(want))

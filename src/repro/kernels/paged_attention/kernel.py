@@ -1,29 +1,33 @@
 """Pallas paged-attention decode kernel, TPU-targeted.
 
-Batched single-token decode over a paged KV layout: K/V live in a page
-pool (P, page, Hkv·D) — the KV heads flattened into the lane axis, so a
-page is one lane-dense (page, Hkv·D) tile whatever the head count, and a
-head_dim of 64 wastes no lanes — and each batch row addresses its sequence
-through a page table (B, T) of physical page ids (`repro.serving.paging`
-builds both).
+Batched single-token decode over a paged KV layout: K/V live in page
+pools stacked over layers, (L, P, page, Hkv·D) — the KV heads flattened
+into the lane axis, so a page is one lane-dense (page, Hkv·D) tile
+whatever the head count, and a head_dim of 64 wastes no lanes — and each
+batch row addresses its sequence through a page table (B, T) of physical
+page ids (`repro.serving.paging` builds both).  The call reads one layer
+of the stack, in place: the layer loop hands it the whole stacked pool
+and its layer index, so no layer's pool is sliced out per step.
 
-Grid: (batch,).  Page tables and positions arrive through scalar prefetch
-(SMEM), and the pools stay in HBM (`memory_space=ANY`): each program walks
-its row's table and DMAs one whole page of K and of V into VMEM as the
-loop reaches it, double-buffered so page t+1 streams in while page t
-computes.  Every KV head of the row is served from the same page tile:
-the query arrives as a block-diagonal (Hkv, Hkv·D) matrix per query group
-(row h holds head h's query in lanes [h·D, (h+1)·D), zeros elsewhere), so
-scores are one MXU matmul against the page, (Hkv, page), and the
-probabilities times the page give (Hkv, Hkv·D), whose block diagonal is
-each head's output.  The online-softmax recurrence (FlashAttention-2 over
-a scattered KV layout) folds each page into a lane-flat (1, Hkv·D)
-accumulator.  The fori_loop upper bound is pos // page + 1, so pages past
-the row's position are never read.
+Grid: (batch,).  Page tables, positions and the layer index arrive
+through scalar prefetch (SMEM), and the pools stay in HBM
+(`memory_space=ANY`): each program walks its row's table and DMAs one
+whole page of K and of V of its layer into VMEM as the loop reaches it,
+double-buffered so page t+1 streams in while page t computes.  Every KV
+head of the row is served from the same page tile: the query arrives as a
+block-diagonal (Hkv, Hkv·D) matrix per query group (row h holds head h's
+query in lanes [h·D, (h+1)·D), zeros elsewhere), so scores are one MXU
+matmul against the page, (Hkv, page), and the probabilities times the
+page give (Hkv, Hkv·D), whose block diagonal is each head's output.  The
+online-softmax recurrence (FlashAttention-2 over a scattered KV layout)
+folds each page into a lane-flat (1, Hkv·D) accumulator.  The fori_loop
+upper bound is pos // page + 1, so pages past the row's position are
+never read.
 
-Contract matches `repro.kernels.paged_attention.ref.paged_attention_ref`
-(its jnp gather math is the oracle in tests, and mirrors the paged decode
-path in `repro.nn.attention`).
+Contract: a call on layer l matches
+`repro.kernels.paged_attention.ref.paged_attention_ref` on the pools'
+layer l (its jnp gather math is the oracle in tests, and mirrors the
+paged decode path in `repro.nn.attention`).
 """
 from __future__ import annotations
 
@@ -38,21 +42,23 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1.0e30
 
 
-def _kernel(tab_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
-            *, page: int, head_dim: int, n_blocks: int, scale: float):
-    # tab_ref (B*T,) / pos_ref (B,) in SMEM; q_ref (1, G, Hkv, Hkv*D)
-    # block-diagonal queries; o_ref (1, G, 1, Hkv*D); k/v_hbm
-    # (P, page, Hkv*D) in HBM; k/v_buf (2, page, Hkv*D) in VMEM
+def _kernel(tab_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+            v_buf, sem, *, page: int, head_dim: int, n_blocks: int,
+            scale: float):
+    # tab_ref (B*T,) / pos_ref (B,) / layer_ref (1,) in SMEM; q_ref
+    # (1, G, Hkv, Hkv*D) block-diagonal queries; o_ref (1, G, 1, Hkv*D);
+    # k/v_hbm (L, P, page, Hkv*D) in HBM; k/v_buf (2, page, Hkv*D) in VMEM
     b = pl.program_id(0)
     G, Hkv, HD = q_ref.shape[1:]
     pos = pos_ref[b]
+    layer = layer_ref[0]
     hi = pos // page + 1                # blocks holding positions <= pos
 
     def copies(t, slot):
         pid = tab_ref[b * n_blocks + t]
-        return (pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[slot],
+        return (pltpu.make_async_copy(k_hbm.at[layer, pid], k_buf.at[slot],
                                       sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[slot],
+                pltpu.make_async_copy(v_hbm.at[layer, pid], v_buf.at[slot],
                                       sem.at[1, slot]))
 
     for c in copies(0, 0):
@@ -109,16 +115,17 @@ def _kernel(tab_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
 
 def paged_attention_pallas(
     q: jax.Array,        # (B, G, Hkv, D) query heads, group-major
-    k_pool: jax.Array,   # (P, page, Hkv*D)
+    k_pool: jax.Array,   # (L, P, page, Hkv*D)
     v_pool: jax.Array,
     tables: jax.Array,   # (B, T) int32 physical page ids
     pos: jax.Array,      # (B,) int32 current position per row
+    layer: jax.Array,    # () int32 layer of the stacked pools to read
     *,
     interpret: bool,
 ) -> jax.Array:
     """Returns (B, G, Hkv, D)."""
     B, G, Hkv, D = q.shape
-    page, HD = k_pool.shape[1:]
+    page, HD = k_pool.shape[2:]
     T = tables.shape[1]
     # block-diagonal queries: qd[b, g, h, h'*D + d] = q[b, g, h, d] iff h == h'
     qd = jnp.einsum("bghd,hk->bghkd", q, jnp.eye(Hkv, dtype=q.dtype))
@@ -127,13 +134,13 @@ def paged_attention_pallas(
                              scale=1.0 / math.sqrt(D))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[pl.BlockSpec((1, G, Hkv, HD),
-                               lambda b, tab, pos: (b, 0, 0, 0)),
+                               lambda b, tab, pos, layer: (b, 0, 0, 0)),
                   hbm, hbm],
         out_specs=pl.BlockSpec((1, G, 1, HD),
-                               lambda b, tab, pos: (b, 0, 0, 0)),
+                               lambda b, tab, pos, layer: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, page, HD), k_pool.dtype),
             pltpu.VMEM((2, page, HD), v_pool.dtype),
@@ -148,5 +155,6 @@ def paged_attention_pallas(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="paged_attention",
-    )(tables.reshape(-1), pos, qd, k_pool, v_pool)
+    )(tables.reshape(-1), pos, jnp.reshape(layer, (1,)).astype(jnp.int32),
+      qd, k_pool, v_pool)
     return o.reshape(B, G, Hkv, D)

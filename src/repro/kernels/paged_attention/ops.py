@@ -10,12 +10,15 @@ from repro.kernels import interpret_mode
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
 
 
-def paged_attention(q, k_pool, v_pool, tables, pos, *, interpret=None):
+def paged_attention(q, k_pool, v_pool, tables, pos, layer, *,
+                    interpret=None):
     """q: (B, H, D) one query token per row, heads flat in KV-major order
-    (head h serves KV head h // (H/Hkv)); k/v_pool: (P, page, Hkv*D) page
-    pools, KV heads flattened into the last axis; tables: (B, T) int32
-    physical page ids; pos: (B,) int32 per-row positions.  Returns
-    (B, H, D).
+    (head h serves KV head h // (H/Hkv)); k/v_pool: (L, P, page, Hkv*D)
+    page pools stacked over layers, KV heads flattened into the last axis;
+    tables: (B, T) int32 physical page ids; pos: (B,) int32 per-row
+    positions; layer: the () int32 index of the layer to read, in place.
+    A single layer's pool is passed as a stack of one, at layer 0.
+    Returns (B, H, D).
 
     `interpret=None` resolves from the platform (`repro.kernels.
     interpret_mode`: compiled on TPU, interpreted on CPU); pass an explicit
@@ -23,11 +26,15 @@ def paged_attention(q, k_pool, v_pool, tables, pos, *, interpret=None):
     non-divisible head count or accepting a non-int32 page table (a float
     table would truncate physical page ids)."""
     B, H, D = q.shape
-    Hkv = k_pool.shape[2] // D
-    if k_pool.shape[2] % D != 0:
+    if k_pool.ndim != 4:
         raise ValueError(
-            f"paged_attention: pool width {k_pool.shape[2]} is not a "
-            f"multiple of head_dim {D} — pools are (P, page, Hkv*D)")
+            f"paged_attention: pools are (L, P, page, Hkv*D) stacked over "
+            f"layers, got shape {k_pool.shape}")
+    Hkv = k_pool.shape[3] // D
+    if k_pool.shape[3] % D != 0:
+        raise ValueError(
+            f"paged_attention: pool width {k_pool.shape[3]} is not a "
+            f"multiple of head_dim {D} — pools are (L, P, page, Hkv*D)")
     if H % Hkv != 0:
         raise ValueError(
             f"paged_attention: {H} query heads are not divisible by "
@@ -41,6 +48,6 @@ def paged_attention(q, k_pool, v_pool, tables, pos, *, interpret=None):
     G = H // Hkv
     qg = q.reshape(B, Hkv, G, D).transpose(0, 2, 1, 3)
     o = paged_attention_pallas(qg, k_pool, v_pool, tables,
-                               pos.astype(jnp.int32),
+                               pos.astype(jnp.int32), layer,
                                interpret=bool(interpret))
     return o.transpose(0, 2, 1, 3).reshape(B, H, D)

@@ -285,6 +285,7 @@ def attention(
     make_cache: bool = False,
     cache_len: int = 0,
     page_table: Optional[jax.Array] = None,
+    cache_layer=0,
     kernel_backend: str = "xla",
     kernel_interpret: bool = False,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
@@ -297,10 +298,12 @@ def attention(
       this chunk's K/V land at absolute positions [cache_pos, cache_pos+S)
       of a full-length staging cache (windowed layers store every position
       and mask the window; no ring until arena install)
-    * paged decode: cache leaves are page pools (P, page, ...) — GQA K/V
-      pools (P, page, Hkv*D) — and page_table (B, T) maps each row's
-      logical blocks to physical pages (cache_pos must be a per-row (B,)
-      vector)
+    * paged decode: cache leaves are page pools stacked over layers
+      (L, P, page, ...) — GQA K/V pools (L, P, page, Hkv*D) — read and
+      written in place at layer `cache_layer` (a single layer's pools
+      pass as a stack of one, at layer 0), and page_table (B, T) maps
+      each row's logical blocks to physical pages (cache_pos must be a
+      per-row (B,) vector); the returned cache is the whole stack
 
     kernel_backend="pallas" routes eligible paged GQA decode
     (single-token, no sliding window, no logit softcap) through the
@@ -324,7 +327,8 @@ def attention(
         return _mla_attention(params, x, cfg, positions=positions,
                               prefix_len=prefix_len, cache=cache,
                               cache_pos=cache_pos, make_cache=make_cache,
-                              cache_len=cache_len, page_table=page_table)
+                              cache_len=cache_len, page_table=page_table,
+                              cache_layer=cache_layer)
 
     with jax.named_scope("attn_proj"):
         q, k, v = _gqa_qkv(params, x, cfg, positions)
@@ -342,29 +346,34 @@ def attention(
                 # Pallas kernel walks only pages at/below each row's
                 # position — no full-width gather.  Windowed/softcap
                 # layers (none in the paged configs today) fall back to
-                # the XLA path below.  int8 pools are dequantized
-                # elementwise first: identical values to the XLA path's
+                # the XLA path below.  The kernel reads layer
+                # `cache_layer` of the stacked pools in place.  int8 pools
+                # are dequantized elementwise first (this layer's, as a
+                # stack of one): identical values to the XLA path's
                 # gather-then-dequant.
                 H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
                 with jax.named_scope("kv_pool"):
                     new_cache = _paged_scatter_gqa(cache, k, v, cfg,
-                                                   cache_pos, page_table)
+                                                   cache_pos, page_table,
+                                                   cache_layer)
                     if cfg.kv_cache_dtype == "int8":
-                        kp = _pool_dequant(new_cache["k"],
-                                           new_cache["k_scale"], k.dtype)
-                        vp = _pool_dequant(new_cache["v"],
-                                           new_cache["v_scale"], v.dtype)
+                        kp, vp = (_pool_dequant(
+                            new_cache[n][cache_layer],
+                            new_cache[n + "_scale"][cache_layer],
+                            k.dtype)[None] for n in ("k", "v"))
+                        layer = 0
                     else:
                         kp, vp = new_cache["k"], new_cache["v"]
+                        layer = cache_layer
                 with jax.named_scope("paged_attention"):
                     o = _pallas_paged(q[:, 0].reshape(B, H, D), kp, vp,
-                                      page_table, cache_pos,
+                                      page_table, cache_pos, layer,
                                       interpret=kernel_interpret)
                     o = o.reshape(B, 1, Hkv, H // Hkv, D)
             else:
                 with jax.named_scope("kv_pool"):
                     kc, vc, new_cache = _paged_append_gqa(
-                        cache, k, v, cfg, cache_pos, page_table)
+                        cache, k, v, cfg, cache_pos, page_table, cache_layer)
                 Sc = kc.shape[1]
                 kpos = jnp.arange(Sc)[None, :]
                 cp = cache_pos[:, None]
@@ -502,33 +511,35 @@ def attention(
     return y, new_cache
 
 
-def _paged_ops(pool_leaf, cache_pos, page_table):
+def _paged_ops(pool_leaf, cache_pos, page_table, layer):
     """Scatter/gather closures over one page pool shape family.
 
-    Pool leaves carry (P, page, ...); cache_pos (B,) is each row's write
-    position and page_table (B, T) its block→physical-page map.  `scatter`
-    writes this step's (B, 1, ...) entries at (physical page, offset);
-    `gather` rebuilds the row-ordered (B, T·page, ...) view.  Updated pools
-    keep the slot path's sharding annotation (page axis in the batch role,
-    no-op without a mesh) so sharded serving doesn't silently lose the KV
-    constraint.
+    Pool leaves carry (L, P, page, ...), page pools stacked over layers;
+    `layer` is the one this call reads and writes, cache_pos (B,) each
+    row's write position and page_table (B, T) its block→physical-page
+    map.  `scatter` writes this step's (B, 1, ...) entries in place at
+    (layer, physical page, offset); `gather` rebuilds the layer's
+    row-ordered (B, T·page, ...) view in one gather.  Neither slices the
+    layer's pool out of the stack.  Updated pools keep the slot path's
+    sharding annotation (page axis in the batch role, no-op without a
+    mesh) so sharded serving doesn't silently lose the KV constraint.
 
     Each active row's target page is exclusively owned (the arena COWs
     shared pages before the step), so the scatter rows never collide except
     on the reserved scratch page that inactive rows aim at — whose contents
     are never gathered.
     """
-    page = pool_leaf.shape[1]
+    page = pool_leaf.shape[2]
     B, T = page_table.shape
     block, offset = cache_pos // page, cache_pos % page
     phys = page_table[jnp.arange(B), block]
 
     def scatter(pool, new):
-        pool = pool.at[phys, offset].set(new[:, 0].astype(pool.dtype))
-        return shard(pool, "batch", "sp", *((None,) * (pool.ndim - 2)))
+        pool = pool.at[layer, phys, offset].set(new[:, 0].astype(pool.dtype))
+        return shard(pool, None, "batch", "sp", *((None,) * (pool.ndim - 3)))
 
     def gather(pool):
-        return pool[page_table].reshape((B, T * page) + pool.shape[2:])
+        return pool[layer, page_table].reshape((B, T * page) + pool.shape[3:])
 
     return scatter, gather
 
@@ -547,12 +558,14 @@ def _pool_dequant(codes: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     return _flat_heads(_kv_dequant(split, scale, dtype))
 
 
-def _paged_scatter_gqa(cache, k, v, cfg: ModelConfig, cache_pos, page_table):
-    """Write this step's (B, 1, Hkv, D) K/V projections into GQA page pools
-    (P, page, Hkv*D) (+ int8 scales (P, page, Hkv)); returns the updated
-    cache.  The Pallas path reads the pools through the page table
-    itself; `_paged_append_gqa` gathers them for the XLA path."""
-    scatter, _ = _paged_ops(cache["k"], cache_pos, page_table)
+def _paged_scatter_gqa(cache, k, v, cfg: ModelConfig, cache_pos, page_table,
+                       layer):
+    """Write this step's (B, 1, Hkv, D) K/V projections into layer `layer`
+    of stacked GQA page pools (L, P, page, Hkv*D) (+ int8 scales
+    (L, P, page, Hkv)); returns the updated cache.  The Pallas path reads
+    the pools through the page table itself; `_paged_append_gqa` gathers
+    them for the XLA path."""
+    scatter, _ = _paged_ops(cache["k"], cache_pos, page_table, layer)
     if cfg.kv_cache_dtype == "int8":
         kq, ks = _kv_quant(k)
         vq, vs = _kv_quant(v)
@@ -564,12 +577,15 @@ def _paged_scatter_gqa(cache, k, v, cfg: ModelConfig, cache_pos, page_table):
             "v": scatter(cache["v"], _flat_heads(v))}
 
 
-def _paged_append_gqa(cache, k, v, cfg: ModelConfig, cache_pos, page_table):
+def _paged_append_gqa(cache, k, v, cfg: ModelConfig, cache_pos, page_table,
+                      layer):
     """Paged decode append + gather for GQA caches: scatter as
     `_paged_scatter_gqa`, then return (kc, vc, new_cache) with kc/vc
-    gathered to (B, T·page, Hkv, D) in logical position order."""
-    new_cache = _paged_scatter_gqa(cache, k, v, cfg, cache_pos, page_table)
-    _, gather = _paged_ops(cache["k"], cache_pos, page_table)
+    gathered from layer `layer` to (B, T·page, Hkv, D) in logical position
+    order."""
+    new_cache = _paged_scatter_gqa(cache, k, v, cfg, cache_pos, page_table,
+                                   layer)
+    _, gather = _paged_ops(cache["k"], cache_pos, page_table, layer)
     shape = k.shape[:1] + (-1,) + k.shape[2:]
     if cfg.kv_cache_dtype == "int8":
         kc = _pool_dequant(gather(new_cache["k"]),
@@ -596,7 +612,7 @@ def _dus_batch(cache: jax.Array, new: jax.Array, pos) -> jax.Array:
 # ==================================================================== MLA
 def _mla_attention(params, x, cfg: ModelConfig, *, positions, prefix_len,
                    cache, cache_pos, make_cache, cache_len,
-                   page_table=None):
+                   page_table=None, cache_layer=0):
     B, S, _ = x.shape
     H = cfg.n_heads
     nope, rope_d, vdim = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -624,10 +640,11 @@ def _mla_attention(params, x, cfg: ModelConfig, *, positions, prefix_len,
         # (≈32× here; EXPERIMENTS.md §Perf iteration 6).
         if page_table is not None:
             # Paged latent cache: scatter this token's (c_kv, k_rope) into
-            # its physical page, gather the row's pages back into logical
-            # order, then run the same absorbed math.
+            # its physical page of layer `cache_layer`, gather the row's
+            # pages back into logical order, then run the same absorbed
+            # math.
             scatter, gather = _paged_ops(cache["c_kv"], cache_pos,
-                                         page_table)
+                                         page_table, cache_layer)
             new_cache = {"c_kv": scatter(cache["c_kv"], c_kv),
                          "k_rope": scatter(cache["k_rope"], k_rope)}
             ckv_c = gather(new_cache["c_kv"])

@@ -116,13 +116,16 @@ def apply_block(
     make_cache: bool = False,
     cache_len: int = 0,
     page_table=None,
+    cache_layer=0,
     valid_len=None,
     kernel_backend: str = "xla",
     kernel_interpret: bool = False,
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
     """Returns (x, new_cache, aux_loss).  ``valid_len`` marks how many of a
     chunked-prefill chunk's tokens are real (recurrent layers freeze their
-    state past it; attention masks make it irrelevant there)."""
+    state past it; attention masks make it irrelevant there).  With a
+    ``page_table`` the cache holds page pools stacked over layers and this
+    block reads and writes layer ``cache_layer`` of them in place."""
     kind = layer_kind(cfg, i)
     aux = jnp.zeros((), jnp.float32)
     with jax.named_scope("attn_proj"):
@@ -142,7 +145,8 @@ def apply_block(
         bp["attn"], h, cfg, layer_window=window, positions=positions,
         prefix_len=prefix_len, cache=attn_cache, cache_pos=cache_pos,
         make_cache=make_cache, cache_len=cache_len, page_table=page_table,
-        kernel_backend=kernel_backend, kernel_interpret=kernel_interpret)
+        cache_layer=cache_layer, kernel_backend=kernel_backend,
+        kernel_interpret=kernel_interpret)
 
     new_cache: Optional[Params] = None
     if kind == "hybrid":
@@ -254,6 +258,10 @@ def apply_stack(
     for seg_idx, (start, length, scanned) in enumerate(plan):
         seg_params = params["segments"][seg_idx]
         seg_cache = caches[seg_idx] if caches is not None else None
+        # page pools are read and written in place at a layer index: a
+        # scanned segment's (L, P, page, ...) pools ride the loop's carry
+        # whole, a single layer's pass as a stack of one (a bitcast)
+        paged = page_table is not None and seg_cache is not None
         block = functools.partial(
             apply_block, cfg=cfg, i=start, positions=positions,
             prefix_len=prefix_len, cache_pos=cache_pos,
@@ -266,6 +274,10 @@ def apply_stack(
                 x, nc, a = jax.checkpoint(
                     lambda b, v: block(b, v, cache=None),
                     prevent_cse=False)(seg_params, x)
+            elif paged:
+                x, nc, a = block(seg_params, x, cache=jax.tree.map(
+                    lambda p: p.reshape((1,) + p.shape), seg_cache))
+                nc = jax.tree.map(lambda p: p.reshape(p.shape[1:]), nc)
             else:
                 x, nc, a = block(seg_params, x, cache=seg_cache)
             new_caches.append(nc)
@@ -288,6 +300,18 @@ def apply_stack(
 
             (x, aux_total), ncs = jax.lax.scan(body, (x, aux_total),
                                                seg_params)
+        elif paged:
+            # decode only: nothing differentiates it, so no remat
+            def body(carry, layer_in):
+                xx, aux, pools = carry
+                bp, layer = layer_in
+                xx_new, pools, a = block(bp, xx, cache=pools,
+                                         cache_layer=layer)
+                return (xx_new, aux + a, pools), None
+
+            (x, aux_total, ncs), _ = jax.lax.scan(
+                body, (x, aux_total, seg_cache),
+                (seg_params, jnp.arange(length, dtype=jnp.int32)))
         else:
             def body(carry, layer_in):
                 xx, aux = carry

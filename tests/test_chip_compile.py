@@ -8,6 +8,7 @@ process at a time may load the TPU library, and every xdist worker imports
 this file."""
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +16,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
+from repro.launch.steps import cached_fused_paged_serve_step
+from repro.nn.config import ModelConfig
+from repro.nn.model import init_serving_params
+from repro.serving.paging import init_page_pool
 
 FULL_POOL_PAGES = 1025       # a deployment-sized pool of 16-token pages
 
@@ -40,11 +45,62 @@ def one_chip():
     (8, 8, 8, 128),     # a GQA config: 8 KV heads of 128, 8 queries each
 ], ids=["minicpm-2b", "gqa-8x128"])
 def test_paged_attention_compiles_for_v5e(one_chip, B, G, Hkv, D):
-    page, T = 16, 256
+    L, page, T = 4, 16, 256
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    pool = s((FULL_POOL_PAGES, page, Hkv * D), jnp.bfloat16)
+    pool = s((L, FULL_POOL_PAGES, page, Hkv * D), jnp.bfloat16)
     compiled = jax.jit(functools.partial(
         paged_attention_pallas, interpret=False)).lower(
             s((B, G, Hkv, D), jnp.bfloat16), pool, pool,
-            s((B, T), jnp.int32), s((B,), jnp.int32)).compile()
+            s((B, T), jnp.int32), s((B,), jnp.int32),
+            s((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# one optimized-HLO instruction with an array result: name, dims, opcode
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]"
+                    r"(?:\{[^}]*\})? ([\w\-]+)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def test_decode_layer_loop_keeps_page_pools_in_place(one_chip):
+    """The fused paged decode step of a scanned GQA stack, compiled for
+    v5e, touches its deployment-sized page pools only through the
+    kernel and the new token's in-place page scatter: no op outside the
+    kernel copies, slices out or writes back a whole pool, stacked or
+    one layer's."""
+    cfg = ModelConfig(name="gqa-scan4", family="dense", n_layers=4,
+                      d_model=1024, n_heads=16, n_kv_heads=8, head_dim=128,
+                      d_ff=2048, vocab=1024)
+    rows, page, T = 8, 16, 64
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_serving_params(jax.random.PRNGKey(0), cfg)))
+    pools = on_chip(jax.eval_shape(
+        lambda: init_page_pool(cfg, FULL_POOL_PAGES, page)))
+    arg = lambda dt, *shape: jax.ShapeDtypeStruct(shape or (rows,), dt,
+                                                  sharding=one_chip)
+    step = cached_fused_paged_serve_step.__wrapped__(cfg, "pallas", False)
+    hlo = step.lower(params, arg(jnp.int32), pools, arg(jnp.int32),
+                     arg(jnp.int32, rows, T), arg(jnp.float32),
+                     arg(jnp.int32), arg(jnp.uint32, rows, 2),
+                     arg(jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+    layer = (FULL_POOL_PAGES, page, cfg.n_kv_heads * cfg.head_dim)
+    shapes = {",".join(map(str, d)) for d in (layer, (4,) + layer)}
+    passes = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m and m.group(2) in shapes and m.group(3) not in (
+                "parameter", "get-tuple-element", "bitcast"):
+            name = _OP_NAME.search(line)
+            passes.append((m.group(1), m.group(3), name and name.group(1)))
+    # one scatter per pool (K and V), each in its own in-place fusion
+    assert sorted(op for _, op, _ in passes) == [
+        "fusion", "fusion", "scatter", "scatter"], passes
+    assert all(name.endswith("/kv_pool/scatter") for *_, name in passes), \
+        passes

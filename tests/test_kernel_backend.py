@@ -15,6 +15,7 @@ import pytest
 from repro.configs import get_config
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.nn.model import init_params
+from repro.nn.transformer import stack_plan
 from repro.serving import EngineModel, ServingEngine, SchedulerConfig
 from repro.serving.sampling import request_key, sample_token, sample_tokens
 
@@ -27,8 +28,8 @@ PAGE = 4
 def _ops_inputs(H=4, Hkv=2, D=8, P=6, T=3, B=2):
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(B, H, D)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(1, P, PAGE, Hkv * D)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(1, P, PAGE, Hkv * D)).astype(np.float32))
     tables = jnp.asarray(rng.integers(0, P, (B, T)), jnp.int32)
     pos = jnp.asarray([3, 5], jnp.int32)
     return q, kp, vp, tables, pos
@@ -37,19 +38,19 @@ def _ops_inputs(H=4, Hkv=2, D=8, P=6, T=3, B=2):
 def test_ops_rejects_non_divisible_heads():
     q, kp, vp, tables, pos = _ops_inputs(H=5, Hkv=2)
     with pytest.raises(ValueError, match="not divisible"):
-        paged_attention(q, kp, vp, tables, pos, interpret=True)
+        paged_attention(q, kp, vp, tables, pos, 0, interpret=True)
 
 
 def test_ops_rejects_non_int32_tables():
     q, kp, vp, tables, pos = _ops_inputs()
     with pytest.raises(ValueError, match="int32"):
-        paged_attention(q, kp, vp, tables.astype(jnp.float32), pos,
+        paged_attention(q, kp, vp, tables.astype(jnp.float32), pos, 0,
                         interpret=True)
 
 
 def test_ops_explicit_interpret_runs():
     q, kp, vp, tables, pos = _ops_inputs()
-    out = paged_attention(q, kp, vp, tables, pos, interpret=True)
+    out = paged_attention(q, kp, vp, tables, pos, 0, interpret=True)
     assert out.shape == q.shape
     assert bool(jnp.all(jnp.isfinite(out)))
 
@@ -115,6 +116,35 @@ def test_pallas_engine_tokens_match_xla(int8):
         assert got == base, (backend, fuse)
         assert stats["cow"] == base_stats["cow"]
         assert stats["preempt"] == base_stats["preempt"]
+
+
+@pytest.mark.parametrize("n_layers", [4, 2], ids=["scanned", "unscanned"])
+def test_pallas_fused_decode_matches_xla_per_stack_plan(n_layers):
+    """The fused paged decode gives the same tokens on both backends for a
+    scanned stack (page pools stacked over its layers, carried whole
+    through the layer loop) and an unscanned one (fewer layers than
+    `stack_plan`'s min_group: each layer's pools pass as a stack of
+    one)."""
+    cfg = dc.replace(get_config("yi-34b", smoke=True), n_layers=n_layers)
+    assert [s for *_, s in stack_plan(cfg)] == (
+        [True] if n_layers >= 4 else [False] * n_layers)
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(1, cfg.vocab, n).tolist() for n in (5, 11, 3)]
+
+    def tokens(backend):
+        eng = ServingEngine(
+            [EngineModel("a", params, cfg, kv_slots=3, max_seq=32,
+                         kv_layout="paged", page_size=PAGE, n_pages=24,
+                         kernel_backend=backend)],
+            sched=SchedulerConfig(max_prefill_per_step=2))
+        reqs = [eng.submit("a", p, max_new_tokens=7) for p in prompts]
+        eng.run()
+        return [tuple(r.generated) for r in reqs]
+
+    base = tokens("xla")
+    assert all(len(t) == 7 for t in base)
+    assert tokens("pallas") == base
 
 
 def test_sample_syncs_at_most_one_per_step():

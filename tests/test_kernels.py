@@ -89,7 +89,8 @@ def test_paged_attention_kernel_sweep(b, h, hkv, d, pool, page, t, dtype):
         np.stack([rng.choice(pool, t, replace=False) for _ in range(b)]),
         jnp.int32)
     pos = jnp.asarray(rng.integers(0, t * page, b), jnp.int32)
-    o = paged_attention(q, kp, vp, tables, pos, interpret=True)
+    o = paged_attention(q, kp[None], vp[None], tables, pos, 0,
+                        interpret=True)
     r = paged_attention_ref(q, kp, vp, tables, pos)
     tol = 2e-2 if dtype == jnp.bfloat16 else 3e-4
     np.testing.assert_allclose(np.asarray(o, np.float32),
@@ -107,14 +108,41 @@ def test_paged_attention_masks_stale_pages():
     # disjoint tables: a page stale for one row must not be live in another
     tables = rng.permutation(pool).reshape(b, t)
     pos = np.asarray([5, 9])
-    base = paged_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+    base = paged_attention(q, jnp.asarray(kp)[None], jnp.asarray(vp)[None],
                            jnp.asarray(tables, np.int32),
-                           jnp.asarray(pos, np.int32), interpret=True)
+                           jnp.asarray(pos, np.int32), 0, interpret=True)
     for row in range(b):
         for blk in range(pos[row] // page + 1, t):
             kp[tables[row, blk]] = 1e4 * rng.standard_normal((page, hkv * d))
             vp[tables[row, blk]] = 1e4
-    out = paged_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+    out = paged_attention(q, jnp.asarray(kp)[None], jnp.asarray(vp)[None],
                           jnp.asarray(tables, np.int32),
-                          jnp.asarray(pos, np.int32), interpret=True)
+                          jnp.asarray(pos, np.int32), 0, interpret=True)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
+
+
+@pytest.mark.parametrize("layer", ["first", "middle", "last"])
+@pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2)], ids=["mha", "gqa"])
+def test_paged_attention_reads_its_layer_of_stacked_pools(h, hkv, layer):
+    """Stacked (L, P, page, Hkv*D) pools read at a layer index match the
+    oracle on that layer's own pool; other layers hold other values."""
+    L, b, d, pool, page, t = 5, 3, 16, 9, 8, 4
+    li = {"first": 0, "middle": L // 2, "last": L - 1}[layer]
+    rng = np.random.default_rng(11 + h + li)
+    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((L, pool, page, hkv * d)),
+                     jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((L, pool, page, hkv * d)),
+                     jnp.float32)
+    tables = jnp.asarray(
+        np.stack([rng.choice(pool, t, replace=False) for _ in range(b)]),
+        jnp.int32)
+    pos = jnp.asarray(rng.integers(0, t * page, b), jnp.int32)
+    o = paged_attention(q, kp, vp, tables, pos, jnp.int32(li),
+                        interpret=True)
+    r = paged_attention_ref(q, kp[li], vp[li], tables, pos)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(r), rtol=3e-4,
+                               atol=3e-4)
+    other = paged_attention_ref(q, kp[(li + 1) % L], vp[(li + 1) % L],
+                                tables, pos)
+    assert not np.allclose(np.asarray(o), np.asarray(other), atol=1e-2)

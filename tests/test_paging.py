@@ -3,6 +3,8 @@ decode equivalence with the contiguous cache path, and end-to-end paged
 engine behavior — token-for-token against the sequential oracle, requests
 beyond the old per-slot max_seq, prefix sharing with COW, and preemption
 under pool exhaustion."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +13,7 @@ import pytest
 from repro.configs import get_config
 from repro.launch.steps import cached_prefill_step, cached_serve_step
 from repro.nn.model import decode_step, init_params, prefill
+from repro.nn.transformer import stack_plan
 from repro.serving import (EngineModel, PageAllocator, PagedKVArena,
                            SchedulerConfig, ServingEngine)
 from repro.serving.paging import _cached_page_write, init_page_pool
@@ -143,11 +146,20 @@ def test_allocator_property_random_ops():
 
 
 # ------------------------------------------------- nn-level paged decode
-@pytest.mark.parametrize("arch", ["gemma-7b", "deepseek-v2-lite-16b"])
-def test_paged_decode_matches_contiguous(arch):
+@pytest.mark.parametrize("arch,n_layers", [
+    ("gemma-7b", 0), ("deepseek-v2-lite-16b", 0), ("yi-34b", 4),
+    ("deepseek-v2-lite-16b", 5),
+], ids=["gemma-7b", "deepseek-v2-lite-16b", "yi-34b-scan4",
+        "deepseek-v2-lite-16b-scan4"])
+def test_paged_decode_matches_contiguous(arch, n_layers):
     """decode_step over a page pool (scattered physical pages) must equal
-    decode_step over the contiguous cache — GQA and MLA latent caches."""
+    decode_step over the contiguous cache — GQA and MLA latent caches,
+    in unscanned layers (pools of one layer) and in a scanned segment
+    (pools stacked over its layers, written in place at each layer)."""
     cfg = get_config(arch, smoke=True)
+    if n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers, scan_layers=True)
+        assert any(scanned for *_, scanned in stack_plan(cfg))
     params = init_params(jax.random.PRNGKey(0), cfg)
     ps, n_pages, plen = 4, 8, 6
     toks = jax.random.randint(jax.random.PRNGKey(1), (1, plen), 0,

@@ -1,7 +1,8 @@
 """The cell's system under test and the loop that drives it.
 
 `build_engine` makes a `ServingEngine` with the tenants of a
-configuration file; `warm_up` runs one request of every prefill shape the
+configuration file (its `ModelConfig` is the configuration's family's,
+`bench/families`); `warm_up` runs one request of every prefill shape the
 mix can reach; `Driver` offers the mix's traffic (open or closed loop), calls
 `eng.step()`, and stamps each token at the moment the step that made it
 visible returns.
@@ -23,22 +24,6 @@ HERE = Path(__file__).resolve().parent
 
 def load_config(name: str) -> dict:
     return json.loads((HERE / "configs" / f"{name}.json").read_text())
-
-
-def model_config(name: str, hf: dict):
-    """The engine's `ModelConfig` for a dense Llama-family configuration
-    (Hugging Face keys)."""
-    from repro.nn.config import ModelConfig
-
-    d, h = hf["hidden_size"], hf["num_attention_heads"]
-    return ModelConfig(
-        name=name, family="dense", n_layers=hf["num_hidden_layers"],
-        d_model=d, n_heads=h, n_kv_heads=hf.get("num_key_value_heads", h),
-        head_dim=hf.get("head_dim", d // h), d_ff=hf["intermediate_size"],
-        vocab=hf["vocab_size"], rope_theta=float(hf.get("rope_theta", 1e4)),
-        norm_eps=float(hf["rms_norm_eps"]),
-        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
-        scan_layers=True)
 
 
 def tenants(conf: dict) -> List[str]:

@@ -1,9 +1,9 @@
 """kv_pool_pass_ms: device milliseconds per traced step that decoded of
-the decode program's passes over whole K/V page pools outside the
-paged-attention kernel: its operations in the `kv_pool` scope (the new
-token's page scatter, the layer loop's per-layer slice and write-back of
-the stacked pool) and the loop's carry copies that XLA makes, which have
-no scope and are found by the pool's shape (`bench/scopes.py`)."""
+the decode program's operations whose result is a whole K/V page pool,
+outside the paged-attention kernel, found by the pool's shape
+(`bench/scopes.py`): with the pools kept in place through the layer loop,
+the new token's page scatters (scope `kv_pool`); any copy, slice or
+write-back of a pool, with or without a scope, adds to it."""
 from bench import scopes
 
 

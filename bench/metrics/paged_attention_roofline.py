@@ -1,8 +1,9 @@
 """paged_attention_roofline: the least time the chip could take for the
 paged-attention calls of the traced steps (their live pages' bytes over
 HBM bandwidth, or their flops over the bf16 peak, whichever is larger;
-`bench/flops.py`) over the summed device time of the kernel's
-operations (`pallas_call` name `paged_attention`), in percent."""
+the configuration's family's yardstick, `bench/families`) over the summed
+device time of the kernel's operations (`pallas_call` name
+`paged_attention`), in percent."""
 from bench import flops, trace_reduce
 
 KERNEL = r"paged_attention"
@@ -14,12 +15,13 @@ def bound(run):
     # no device number without a known chip's peaks (never from a CPU run)
     if tw is None or run.peaks is None:
         return None
+    shape = run.shape
     page = run.conf["engine"]["page_size"]
     nbytes = nflops = 0
     for s in tw.steps:
-        b, f = flops.paged_attention_call(run.shape, s.decode_positions, page)
-        nbytes += b * run.shape.n_layers
-        nflops += f * run.shape.n_layers
+        b, f = shape.paged_attention_call(s.decode_positions, page)
+        nbytes += b * shape.n_layers
+        nflops += f * shape.n_layers
     kernel_s = trace_reduce.kernel_ns(tw.trace, KERNEL, tw.lo, tw.hi) * 1e-9
     if nbytes == 0 or kernel_s <= 0:
         return None

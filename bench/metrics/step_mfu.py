@@ -2,8 +2,8 @@
 length times the chip's bf16 peak, in percent.  Model flops count every
 prefill and decoded token through every layer's matmuls, causal attention
 over its context, and the LM head for each decoded token and each
-chunk's last position (`bench/flops.py`)."""
-from bench import flops
+chunk's last position (the configuration's family's yardstick,
+`bench/families`)."""
 
 
 def read(run):
@@ -11,10 +11,10 @@ def read(run):
     # no device number without a known chip's peaks (never from a CPU run)
     if tw is None or run.peaks is None or tw.hi <= tw.lo or not tw.steps:
         return None
+    shape = run.shape
     total = 0
     for s in tw.steps:
-        total += sum(flops.decode_token_flops(run.shape, p)
-                     for p in s.decode_positions)
-        total += sum(flops.prefill_chunk_flops(run.shape, start, n)
+        total += sum(shape.decode_token_flops(p) for p in s.decode_positions)
+        total += sum(shape.prefill_chunk_flops(start, n)
                      for start, n in s.chunks)
     return 100.0 * total / (tw.seconds * run.peaks.bf16_flops)

@@ -5,12 +5,13 @@ the harness then leaves the metric out of the result line."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from bench import flops
+from bench import families
 from bench.harness import StepRec, Tracked
 
 
@@ -29,7 +30,6 @@ class TraceWindow:
 @dataclasses.dataclass
 class Run:
     conf: dict                      # the configuration file
-    shape: flops.Shape
     peaks: object                   # bench.peaks.Peaks
     window: Tuple[float, float]     # host clock, seconds
     tracked: List[Tracked]
@@ -38,6 +38,11 @@ class Run:
     compile_events: List[Tuple[float, float, str]]   # (clock, seconds, event)
     residency_build_s: float
     trace: Optional[TraceWindow] = None
+
+    @functools.cached_property
+    def shape(self):
+        """The configuration's yardstick (its family's `Shape`)."""
+        return families.shape(self.conf)
 
     @property
     def seconds(self) -> float:

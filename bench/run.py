@@ -172,8 +172,7 @@ def run_cell(cell: dict, conf: dict, mix: dict, units: dict, *, seed: int,
               f"{len(devices)}", file=sys.stderr)
         return 2
 
-    from bench import correctness, harness, traffic, trace_reduce
-    from bench.flops import Shape
+    from bench import correctness, families, harness, traffic, trace_reduce
     from bench.peaks import peaks_for
     from bench.record import Run, TraceWindow, read_all
     from bench.weights import engine_params, make_weights
@@ -187,10 +186,9 @@ def run_cell(cell: dict, conf: dict, mix: dict, units: dict, *, seed: int,
         f"compile cache {cache_dir}")
 
     hf = conf["config"]
-    shape = Shape.from_config(hf)
-    model_cfg = harness.model_config(cell["config"], hf)
-    w = make_weights(seed, shape)
-    params = engine_params(w, model_cfg)
+    model_cfg = families.of(conf).model_config(cell["config"], hf)
+    w = make_weights(seed, conf)
+    params = engine_params(w, conf, model_cfg)
     del w
     tracer = None
     if trace:
@@ -228,7 +226,7 @@ def run_cell(cell: dict, conf: dict, mix: dict, units: dict, *, seed: int,
     stats = dev.memory_stats() or {}
     peak = int(stats.get("peak_bytes_in_use", 0))
 
-    run = Run(conf=conf, shape=shape, peaks=peaks, window=(w0, w1),
+    run = Run(conf=conf, peaks=peaks, window=(w0, w1),
               tracked=driver.tracked, steps=driver.steps,
               setup_s=w0 - T_PROCESS, compile_events=compiles.events,
               residency_build_s=eng.residency_build_s)
@@ -301,7 +299,7 @@ def run_cell(cell: dict, conf: dict, mix: dict, units: dict, *, seed: int,
     log(f"device bytes in use with the engine freed: "
         f"{(dev.memory_stats() or {}).get('bytes_in_use', 0)}")
     t0 = clock()
-    w = make_weights(seed, shape)
+    w = make_weights(seed, conf)
     gap = correctness.gaps(w, conf, seqs)
     log(f"reference: {len(seqs)} requests, {gap.size} served tokens in "
         f"{clock() - t0:.3f} s")

@@ -17,11 +17,15 @@ operands) but not the instruction's `op_name`, so the scope of each op is
 read from the optimized HLO of the program itself, which the engine's own
 jitted step gives again for the run's configuration, and matched to the
 trace's ops by instruction name and result type.  Two kinds of op carry
-no part scope: the layer loop's own per-layer slices and write-backs
-(under the root alone: a scope around the loop would name its slices of
-the stacked weights too) and the ops XLA makes, such as the loop's carry
-copies (no `op_name`).  Passes over the K/V pool are therefore found by
-the pool's shape, outside the `paged_attention` scope.
+no part scope: the layer loop's own slices of the stacked weights (under
+the root alone: a scope around the loop would name them too) and the ops
+XLA makes (no `op_name`).  The layer loop carries the stacked K/V page
+pools whole and writes each layer's new token into them in place (the
+two page scatters in `kv_pool`), so no other op's result is a whole pool.
+A pass over a pool that a change brings back (a copy, a slice, a
+write-back, with or without a scope) is found by the pool's shape,
+stacked or one layer's as the configuration's family gives them
+(`bench/families`), outside the `paged_attention` scope.
 """
 from __future__ import annotations
 
@@ -37,7 +41,7 @@ ROOT_DIR = Path(__file__).resolve().parents[1]
 if __name__ == "__main__":
     sys.path[:0] = [str(ROOT_DIR), str(ROOT_DIR / "src")]
 
-from bench import trace_reduce  # noqa: E402
+from bench import families, trace_reduce  # noqa: E402
 
 PHASE_PREFIX = "engine."
 ROOT = "decode_step"
@@ -206,7 +210,7 @@ def decode_hlo(conf: dict) -> str:
     name = next((p.stem for p in sorted((harness.HERE / "configs")
                                         .glob("*.json"))
                  if harness.load_config(p.stem) == conf), "decode")
-    cfg = harness.model_config(name, conf["config"])
+    cfg = families.of(conf).model_config(name, conf["config"])
     e = conf["engine"]
     backend = e["kernel_backend"]
     interp = interpret_mode() if backend == "pallas" else False
@@ -226,13 +230,12 @@ def decode_hlo(conf: dict) -> str:
                     arg(jnp.int32)).compile().as_text()
 
 
-def pool_dims(conf: dict, shape) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
-    """(stacked, per-layer) dims of one K or V page pool:
-    [L, P+1, page, Hkv*D] and [P+1, page, Hkv*D]."""
+def pool_dims(conf: dict) -> Tuple[Tuple[int, ...], ...]:
+    """Dims of the decode program's K/V page pools, stacked and one
+    layer's, with the page-0 scratch page: the configuration's family's
+    `pool_dims` for `kv_pages` + 1 pages."""
     e = conf["engine"]
-    layer = (e["kv_pages"] + 1, e["page_size"],
-             shape.n_kv_heads * shape.head_dim)
-    return (shape.n_layers,) + layer, layer
+    return families.shape(conf).pool_dims(e["kv_pages"] + 1, e["page_size"])
 
 
 class Program:
@@ -331,8 +334,7 @@ def run_program(run) -> Optional[Program]:
     tw = run.trace
     if tw is None or run.peaks is None:
         return None
-    return Program(parse_hlo(decode_hlo(run.conf)),
-                   pool_dims(run.conf, run.shape))
+    return Program(parse_hlo(decode_hlo(run.conf)), pool_dims(run.conf))
 
 
 def decoded_steps(run) -> int:
@@ -368,11 +370,9 @@ def main(argv=None) -> int:
     if not args.config:
         return 0
     from bench import harness
-    from bench.flops import Shape
 
     conf = harness.load_config(args.config)
-    prog = Program(parse_hlo(decode_hlo(conf)),
-                   pool_dims(conf, Shape.from_config(conf["config"])))
+    prog = Program(parse_hlo(decode_hlo(conf)), pool_dims(conf))
     if not prog.scoped or not n_dec:
         print("the decode program carries no scopes, or no step decoded")
         return 0

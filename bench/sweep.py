@@ -41,8 +41,7 @@ def main() -> int:
     if jax.devices()[0].platform != "tpu":
         print("bench/sweep.py needs a TPU", file=sys.stderr)
         return 2
-    from bench import harness, traffic
-    from bench.flops import Shape
+    from bench import families, harness, traffic
     from bench.record import Run, read_all
     from bench.weights import engine_params, make_weights
     from repro.launch.compile_cache import setup_compile_cache
@@ -55,9 +54,8 @@ def main() -> int:
     if mix["loop"] != "open":
         raise SystemExit("a closed loop has no rate to sweep")
     hf = conf["config"]
-    shape = Shape.from_config(hf)
-    model_cfg = harness.model_config(cell["config"], hf)
-    params = engine_params(make_weights(args.seed, shape), model_cfg)
+    model_cfg = families.of(conf).model_config(cell["config"], hf)
+    params = engine_params(make_weights(args.seed, conf), conf, model_cfg)
     eng = harness.build_engine(conf, model_cfg, params)
     del params
     harness.warm_up(eng, conf, harness.warm_lengths(eng, conf, mix),
@@ -75,7 +73,7 @@ def main() -> int:
         w0, w1 = d.window
         mid = min(queue, key=lambda q: abs(q[0] - (w0 + w1) / 2))[1]
         close = len(eng.scheduler.queue)
-        run = Run(conf=conf, shape=shape, peaks=None, window=d.window,
+        run = Run(conf=conf, peaks=None, window=d.window,
                   tracked=d.tracked, steps=d.steps, setup_s=0.0,
                   compile_events=[],
                   residency_build_s=eng.residency_build_s)

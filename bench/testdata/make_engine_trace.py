@@ -21,35 +21,27 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import jax  # noqa: E402
 
-from bench import harness  # noqa: E402
-from bench.flops import Shape  # noqa: E402
+from bench import families, harness  # noqa: E402
+from bench.tests.conftest import SMOKE_CONF  # noqa: E402
 from bench.weights import engine_params, make_weights  # noqa: E402
 from repro.serving import Tracer  # noqa: E402
 
 OUT = Path(__file__).resolve().parent / "engine_cpu_trace.xplane.pb"
 
-CONF = {
-    "config": {
-        "hidden_size": 64, "intermediate_size": 128,
-        "num_attention_heads": 4, "num_key_value_heads": 2,
-        "num_hidden_layers": 4, "rms_norm_eps": 1e-5,
-        "rope_theta": 10000.0, "tie_word_embeddings": False,
-        "vocab_size": 500,
-    },
-    "engine": {
-        "dtype": "bfloat16", "tenants": ["model"], "decode_rows": 4,
-        "page_size": 8, "kv_pages": 32, "kernel_backend": "xla",
-        "prefill_chunk": 16, "prefill_token_budget": 16,
-        "max_prefill_per_step": 2, "bucket_min": 8, "bucket_growth": 2.0,
-        "staging_growth": 2.0,
-    },
-}
+# the benchmark tests' smoke configuration, with a small pool, the XLA
+# decode path and 16-token chunks
+CONF = dict(SMOKE_CONF, engine={
+    "dtype": "bfloat16", "tenants": ["model"], "decode_rows": 4,
+    "page_size": 8, "kv_pages": 32, "kernel_backend": "xla",
+    "prefill_chunk": 16, "prefill_token_budget": 16,
+    "max_prefill_per_step": 2, "bucket_min": 8, "bucket_growth": 2.0,
+    "staging_growth": 2.0,
+})
 
 
 def main():
-    hf = CONF["config"]
-    cfg = harness.model_config("engine-trace", hf)
-    params = engine_params(make_weights(5, Shape.from_config(hf)), cfg)
+    cfg = families.of(CONF).model_config("engine-trace", CONF["config"])
+    params = engine_params(make_weights(5, CONF), CONF, cfg)
     eng = harness.build_engine(CONF, cfg, params, Tracer())
     for n in (10, 13):
         eng.submit("model", [1 + (3 * n + j) % 499 for j in range(n)],

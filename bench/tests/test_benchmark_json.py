@@ -28,6 +28,7 @@ def test_cells_find_their_files():
         assert c["file"] == f"bench/configs/{c['name']}.json"
         assert sorted(c["reduced"]) == sorted(conf["reduced"])
         importlib.import_module(f"bench.references.{conf['reference']}")
+        importlib.import_module(f"bench.families.{conf['reference']}")
     for w in BENCH["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert w["name"] == f"{w['config']}.{w['traffic']}"

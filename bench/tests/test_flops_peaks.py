@@ -1,19 +1,16 @@
 """Operations and bytes from shapes, checked against hand counts at the
-two configurations' published widths, and the peaks table."""
-import json
-from pathlib import Path
-
+two configurations' published widths and against the counts the
+benchmark read before the yardstick moved into the family modules, and
+the peaks table."""
 import pytest
 
-from bench import flops
+from bench import families, flops, harness, scopes
+from bench.families import llama
 from bench.peaks import PEAKS, peaks_for
-
-CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
 
 def shape(name):
-    return flops.Shape.from_config(
-        json.loads((CONFIGS / f"{name}.json").read_text())["config"])
+    return llama.Shape.from_config(harness.load_config(name)["config"])
 
 
 def test_parameter_counts():
@@ -38,32 +35,63 @@ def test_parameter_counts():
      4 * 56 * 128 * 4 * 32),
 ])
 def test_paged_attention_hand_count(name, page, want_bytes, want_flops):
-    got = flops.paged_attention_call(shape(name), [100], page)
+    got = shape(name).paged_attention_call([100], page)
     assert got == (want_bytes, want_flops)
-    two = flops.paged_attention_call(shape(name), [100, 100], page)
+    two = shape(name).paged_attention_call([100, 100], page)
     assert two == (2 * want_bytes, 2 * want_flops)
-    assert flops.paged_attention_call(shape(name), [], page) == (0, 0)
+    assert shape(name).paged_attention_call([], page) == (0, 0)
 
 
 def test_model_flops_per_token():
     m = shape("minicpm-2b")
     # a decoded token: 2 x (matmul params + head) + 4 x L x H x D x (p+1)
     head = 2304 * 122753
-    assert flops.decode_token_flops(m, 0) == (
+    assert m.decode_token_flops(0) == (
         2 * (40 * 61_046_784 + head) + 4 * 40 * 36 * 64)
     # about 2 x 2.72e9 flops a token at short context
-    assert flops.decode_token_flops(m, 0) == pytest.approx(2 * m.params,
-                                                           rel=0.01)
+    assert m.decode_token_flops(0) == pytest.approx(2 * m.params, rel=0.01)
     # a chunk of n tokens from 0: n matmul passes, causal n(n+1)/2 keys,
     # one head row
     n = 512
-    assert flops.prefill_chunk_flops(m, 0, n) == (
+    assert m.prefill_chunk_flops(0, n) == (
         2 * 40 * 61_046_784 * n + 4 * 40 * 36 * 64 * n * (n + 1) // 2
         + 2 * head)
     # two chunks add up to one
-    assert (flops.prefill_chunk_flops(m, 0, 256)
-            + flops.prefill_chunk_flops(m, 256, 256)
-            == flops.prefill_chunk_flops(m, 0, 512) + 2 * head)
+    assert (m.prefill_chunk_flops(0, 256) + m.prefill_chunk_flops(256, 256)
+            == m.prefill_chunk_flops(0, 512) + 2 * head)
+
+
+# what the benchmark's yardstick read for each configuration before it
+# moved into bench/families/llama.py (bench/flops.py's Shape and functions,
+# bench/scopes.py's pool_dims): every count must stay the same integer
+PINNED = {
+    "yi-34b-s6": dict(
+        params=4_264_651_776, decode_0=7_611_785_216,
+        decode_1000=7_783_817_216, prefill_512_512=3_495_991_181_312,
+        paged_attention=(4_833_440, 33_030_144),
+        pools=((6, 2561, 32, 1024), (2561, 32, 1024))),
+    "minicpm-2b": dict(
+        params=2_724_880_896, decode_0=5_449_757_184,
+        decode_1000=5_818_397_184, prefill_512_512=2_646_091_436_544,
+        paged_attention=(10_211_620, 10_174_464),
+        pools=((40, 577, 16, 2304), (577, 16, 2304))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_yardstick_counts_are_pinned(name):
+    conf = harness.load_config(name)
+    want = PINNED[name]
+    s = families.shape(conf)
+    assert type(s) is llama.Shape
+    assert s.params == want["params"]
+    assert s.decode_token_flops(0) == want["decode_0"]
+    assert s.decode_token_flops(1000) == want["decode_1000"]
+    assert s.prefill_chunk_flops(512, 512) == want["prefill_512_512"]
+    page = conf["engine"]["page_size"]
+    assert s.paged_attention_call([0, 31, 32, 1000], page) == \
+        want["paged_attention"]
+    assert scopes.pool_dims(conf) == want["pools"]
 
 
 def test_least_time_names_its_bound():

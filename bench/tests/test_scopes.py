@@ -12,7 +12,6 @@ import pytest
 
 from bench import scopes
 from bench import trace_reduce as tr
-from bench.flops import Shape
 from bench.metrics import decode_device_ms, engine_idle_ms, kv_pool_pass_ms
 
 DATA = Path(__file__).resolve().parents[1] / "testdata"
@@ -31,7 +30,7 @@ def run_of(trace, lo, hi, n_steps, conf=None, decoded=True):
     return SimpleNamespace(
         trace=SimpleNamespace(trace=trace, lo=lo, hi=hi,
                               steps=[step] * n_steps),
-        peaks=object(), conf=conf, shape=None)
+        peaks=object(), conf=conf)
 
 
 def test_engine_idle_is_idle_inside_the_engine_phases(engine_trace):
@@ -228,9 +227,7 @@ def test_decode_hlo_is_the_engines_scoped_program():
         sys.path.remove(str(DATA))
     conf = copy.deepcopy(CONF)
     text = scopes.decode_hlo(conf)
-    prog = scopes.Program(scopes.parse_hlo(text),
-                          scopes.pool_dims(conf, Shape.from_config(
-                              conf["config"])))
+    prog = scopes.Program(scopes.parse_hlo(text), scopes.pool_dims(conf))
     assert prog.scoped
     found = {scopes.scope_of(n) for n in
              scopes._OP_NAME.findall(text)}

@@ -1,11 +1,14 @@
 """Weights made from `--seed`, on the device, in one jitted call, in the
-type they are served in (matmul weights bf16, norm scales float32).
+type they are served in (matmul weights bf16 unless the engine holds
+them in float32, norm scales float32).
 
 `make_weights` gives the benchmark's own canonical layout, which the plain
-reference reads; `engine_params` re-nests the same arrays into the
-serving engine's parameter tree and checks it against the tree the engine
-itself would build, so a change of the engine's layout fails loudly here
-rather than silently feeding it wrong weights.
+reference reads; its leaves are the configuration's family's
+(`bench/families/<name>.py`).  `engine_params` re-nests the same arrays
+into the serving engine's parameter tree by the family's mapping and
+checks it against the tree the engine itself would build, so a change of
+the engine's layout fails loudly here rather than silently feeding it
+wrong weights.
 """
 from __future__ import annotations
 
@@ -15,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.flops import Shape
+from bench import families
 
 # the engine pads the vocabulary axis of its embedding to this multiple;
 # the padded rows are never a served token and the reference ignores them
@@ -35,27 +38,13 @@ def padded_vocab(vocab: int) -> int:
     return math.ceil(vocab / VOCAB_MULTIPLE) * VOCAB_MULTIPLE
 
 
-def _leaves(s: Shape):
-    """(name, shape, kind) of every canonical leaf, in key order; kind is
-    'embed', 'matmul' (fan-in = shape[-2]) or 'norm'."""
-    L, d, f = s.n_layers, s.d_model, s.d_ff
-    hd, kvd = s.n_heads * s.head_dim, s.n_kv_heads * s.head_dim
-    v = padded_vocab(s.vocab)
-    out = [("embed", (v, d), "embed")]
-    if not s.tied:
-        out.append(("head", (d, v), "matmul"))
-    out += [("attn_norm", (L, d), "norm"), ("wq", (L, d, hd), "matmul"),
-            ("wk", (L, d, kvd), "matmul"), ("wv", (L, d, kvd), "matmul"),
-            ("wo", (L, hd, d), "matmul"), ("mlp_norm", (L, d), "norm"),
-            ("w_gate", (L, d, f), "matmul"), ("w_up", (L, d, f), "matmul"),
-            ("w_down", (L, f, d), "matmul"), ("final_norm", (d,), "norm")]
-    return out
-
-
-def make_weights(seed: int, s: Shape) -> dict:
-    """Canonical weights: flat dict of the leaves `_leaves` names, made on
-    the default device by one jitted call."""
-    leaves = _leaves(s)
+def make_weights(seed: int, conf: dict) -> dict:
+    """Canonical weights of configuration `conf`: flat dict of the leaves
+    its family names, made on the default device by one jitted call.  A
+    leaf's kind sets its values: 'embed' N(0, 0.02) in bf16; 'matmul'
+    N(0, 1 / fan-in) with fan-in shape[-2], in bf16, or 'matmul_f32' in
+    float32; 'norm' 1 + 0.1 N(0, 1) in float32."""
+    leaves = families.of(conf).leaves(conf["config"])
 
     def build(key):
         out = {}
@@ -66,6 +55,8 @@ def make_weights(seed: int, s: Shape) -> dict:
                 out[name] = (z * EMBED_STD).astype(jnp.bfloat16)
             elif kind == "matmul":
                 out[name] = (z / math.sqrt(shape[-2])).astype(jnp.bfloat16)
+            elif kind == "matmul_f32":
+                out[name] = z / math.sqrt(shape[-2])
             else:
                 out[name] = 1.0 + NORM_JITTER * z
         return out
@@ -73,28 +64,14 @@ def make_weights(seed: int, s: Shape) -> dict:
     return jax.jit(build)(seed_key(seed))
 
 
-def engine_params(w: dict, model_cfg) -> dict:
-    """The engine's parameter tree for a dense, scanned stack, holding the
-    canonical arrays themselves (no copy).  Raises when the tree, a shape
-    or a dtype differs from what `repro.nn.model.init_serving_params`
-    builds for `model_cfg`."""
+def engine_params(w: dict, conf: dict, model_cfg) -> dict:
+    """The engine's parameter tree for configuration `conf`, holding the
+    canonical arrays themselves (no copy), as its family nests them.
+    Raises when the tree, a shape or a dtype differs from what
+    `repro.nn.model.init_serving_params` builds for `model_cfg`."""
     from repro.nn.model import init_serving_params
 
-    embedding = {"table": w["embed"]}
-    if "head" in w:
-        embedding["head"] = w["head"]
-    params = {
-        "embedding": embedding,
-        "stack": {"segments": [{
-            "norm1": {"scale": w["attn_norm"]},
-            "attn": {"w_q": w["wq"], "w_k": w["wk"], "w_v": w["wv"],
-                     "w_o": w["wo"]},
-            "norm2": {"scale": w["mlp_norm"]},
-            "mlp": {"w_up": w["w_up"], "w_down": w["w_down"],
-                    "w_gate": w["w_gate"]},
-        }]},
-        "final_norm": {"scale": w["final_norm"]},
-    }
+    params = families.of(conf).engine_params(w, model_cfg)
     want = jax.eval_shape(
         lambda: init_serving_params(jax.random.PRNGKey(0), model_cfg))
     got_def = jax.tree.structure(params)

@@ -1,7 +1,9 @@
 """deepseek-v2-lite-16b [moe]: 27L d=2048, MLA kv_lora=512, 64 routed
 experts top-6 + 2 shared, expert d_ff=1408, first layer dense (d_ff=10944),
-vocab 102400.  [arXiv:2405.04434; hf]"""
-from repro.nn.config import ModelConfig
+vocab 102400; raw top-6 gates (norm_topk_prob false, routed scaling 1),
+YaRN rope scaling (factor 40 over 4096 positions, mscale 0.707).
+[arXiv:2405.04434; hf]"""
+from repro.nn.config import ModelConfig, YarnScaling
 
 
 def full() -> ModelConfig:
@@ -12,7 +14,10 @@ def full() -> ModelConfig:
         kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
         head_dim=192,  # nope + rope
         n_experts=64, n_shared_experts=2, moe_topk=6, d_ff_expert=1408,
-        first_dense_layers=1,
+        first_dense_layers=1, norm_topk_prob=False,
+        rope_scaling=YarnScaling(factor=40.0, original_max_position=4096,
+                                 beta_fast=32.0, beta_slow=1.0,
+                                 mscale=0.707, mscale_all_dim=0.707),
         scan_layers=True,  # grouped scan: [dense, scan·26]
     )
 

@@ -42,6 +42,59 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1.0e30
 
 
+def page_copies(tab_ref, row, n_blocks: int, layer, pools, bufs, sem,
+                pages_per_block: int = 1):
+    """copies(t, slot): the DMAs of block t of batch row `row` — its
+    pages [t * n, t * n + n), n = `pages_per_block` — one per page and
+    pool: layer `layer` of a stacked (L, P, page, W) pool in HBM into
+    rows [j * page, (j + 1) * page) of slot `slot` of that pool's
+    (2, n * page, W) VMEM buffer.  `tab_ref` is the flattened
+    (B * n_blocks,) page table in SMEM (n_blocks: its pages per row); a
+    block's pages past the table's last clamp to it, and pages past a
+    row's live ones are whatever the table names there (the scratch page):
+    the caller masks them.  `sem` is a (len(pools), 2) DMA semaphore
+    array.  The caller starts block 0's before `page_walk`."""
+    if pages_per_block == 1:
+        def copies(t, slot):
+            pid = tab_ref[row * n_blocks + t]
+            return [pltpu.make_async_copy(pool.at[layer, pid],
+                                          buf.at[slot], sem.at[i, slot])
+                    for i, (pool, buf) in enumerate(zip(pools, bufs))]
+        return copies
+
+    def copies(t, slot):
+        out = []
+        for j in range(pages_per_block):
+            idx = jnp.minimum(t * pages_per_block + j, n_blocks - 1)
+            pid = tab_ref[row * n_blocks + idx]
+            for i, (pool, buf) in enumerate(zip(pools, bufs)):
+                page = pool.shape[2]
+                out.append(pltpu.make_async_copy(
+                    pool.at[layer, pid],
+                    buf.at[slot, pl.ds(j * page, page)], sem.at[i, slot]))
+        return out
+    return copies
+
+
+def page_walk(copies, hi, bufs, step, init):
+    """Fold `step(t, pages, carry)` over blocks [0, hi) (`pages`: each
+    pool's block t, in pool order), block t + 1 streaming into the other
+    buffer slot while block t computes.  Returns the final carry."""
+    def body(t, carry):
+        slot = t % 2
+
+        @pl.when(t + 1 < hi)
+        def _():
+            for c in copies(t + 1, 1 - slot):
+                c.start()
+
+        for c in copies(t, slot):
+            c.wait()
+        return step(t, [buf[slot] for buf in bufs], carry)
+
+    return jax.lax.fori_loop(0, hi, body, init)
+
+
 def _kernel(tab_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
             v_buf, sem, *, page: int, head_dim: int, n_blocks: int,
             scale: float):
@@ -53,14 +106,8 @@ def _kernel(tab_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     pos = pos_ref[b]
     layer = layer_ref[0]
     hi = pos // page + 1                # blocks holding positions <= pos
-
-    def copies(t, slot):
-        pid = tab_ref[b * n_blocks + t]
-        return (pltpu.make_async_copy(k_hbm.at[layer, pid], k_buf.at[slot],
-                                      sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[layer, pid], v_buf.at[slot],
-                                      sem.at[1, slot]))
-
+    copies = page_copies(tab_ref, b, n_blocks, layer, (k_hbm, v_hbm),
+                         (k_buf, v_buf), sem)
     for c in copies(0, 0):
         c.start()
 
@@ -72,18 +119,8 @@ def _kernel(tab_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     def spread(x):                      # (Hkv, 1) -> (1, Hkv*D)
         return jnp.sum(jnp.where(own, x, 0.0), axis=0, keepdims=True)
 
-    def body(t, carry):
-        slot = t % 2
-
-        @pl.when(t + 1 < hi)
-        def _():
-            for c in copies(t + 1, 1 - slot):
-                c.start()
-
-        for c in copies(t, slot):
-            c.wait()
-        k = k_buf[slot]                                   # (page, Hkv*D)
-        v = v_buf[slot]
+    def step(t, pages, carry):
+        k, v = pages                                      # (page, Hkv*D)
         kpos = t * page + jax.lax.broadcasted_iota(jnp.int32, (Hkv, page), 1)
         out = []
         for g, (m, l, acc) in enumerate(carry):
@@ -107,7 +144,7 @@ def _kernel(tab_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     init = tuple((jnp.full((Hkv, 1), NEG_INF, jnp.float32),
                   jnp.zeros((Hkv, 1), jnp.float32),
                   jnp.zeros((1, HD), jnp.float32)) for _ in range(G))
-    final = jax.lax.fori_loop(0, hi, body, init)
+    final = page_walk(copies, hi, (k_buf, v_buf), step, init)
     for g, (_, l, acc) in enumerate(final):
         o_ref[0, g] = (acc / spread(jnp.maximum(l, 1e-30))).astype(
             o_ref.dtype)

@@ -1,5 +1,6 @@
-"""Wrapper: groups query heads per KV head (GQA stays native — no pool
-expansion), with the execution mode plumbed in explicitly.
+"""Wrappers: `paged_attention` groups query heads per KV head (GQA stays
+native — no pool expansion); `mla_decode` checks the latent kernel's
+shapes; both take the execution mode explicitly.
 
 No `@jax.jit` here: callers (the serving decode step, the kernel tests)
 jit the surrounding computation, and `interpret` must stay a trace-time
@@ -8,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.kernels import interpret_mode
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
+from repro.kernels.paged_attention.mla_kernel import mla_decode_pallas
 
 
 def paged_attention(q, k_pool, v_pool, tables, pos, layer, *,
@@ -51,3 +53,35 @@ def paged_attention(q, k_pool, v_pool, tables, pos, layer, *,
                                pos.astype(jnp.int32), layer,
                                interpret=bool(interpret))
     return o.transpose(0, 2, 1, 3).reshape(B, H, D)
+
+
+def mla_decode(q_lat, q_rope, c_pool, r_pool, tables, pos, layer, *,
+               scale: float, interpret=None):
+    """Absorbed latent (MLA) decode over paged latent pools: q_lat (B, H,
+    R) = q_nope W_ukᵀ and q_rope (B, H, Wr), cast to the pools' dtype;
+    c_pool (L, P, page, R) and r_pool (L, P, page, Wr) stacked over
+    layers, read in place at `layer` (on a TPU Wr is a whole number of
+    128-lane tiles: the rope keys and queries zero-padded); tables (B, T) int32; pos (B,) int32;
+    `scale` the softmax scale.  Returns the (B, H, R) float32 latent
+    context (the caller applies W_uv and W_o).  `interpret` as for
+    `paged_attention`."""
+    if c_pool.ndim != 4 or r_pool.shape[:3] != c_pool.shape[:3]:
+        raise ValueError(
+            f"mla_decode: pools are (L, P, page, R) and (L, P, page, Wr) "
+            f"with matching leading dims, got {c_pool.shape} and "
+            f"{r_pool.shape}")
+    if q_lat.shape[-1] != c_pool.shape[3] or \
+            q_rope.shape[-1] != r_pool.shape[3]:
+        raise ValueError(
+            f"mla_decode: query widths {q_lat.shape[-1]}/{q_rope.shape[-1]}"
+            f" do not match pool widths {c_pool.shape[3]}/{r_pool.shape[3]}")
+    if tables.dtype != jnp.int32:
+        raise ValueError(
+            f"mla_decode: page table dtype {tables.dtype} must be int32 "
+            "(physical page ids)")
+    if interpret is None:
+        interpret = interpret_mode()
+    return mla_decode_pallas(q_lat.astype(c_pool.dtype),
+                             q_rope.astype(r_pool.dtype), c_pool, r_pool,
+                             tables, pos.astype(jnp.int32), layer,
+                             scale=float(scale), interpret=bool(interpret))

@@ -10,6 +10,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.data.pipeline import DataConfig, make_batch_specs
@@ -52,8 +53,9 @@ def make_prefill_step(cfg: ModelConfig, cache_len: int):
 
 
 # Root of the decode programs' named scopes; their parts are embed,
-# attn_proj, kv_pool, paged_attention, mlp, lm_head (nn.model,
-# nn.transformer, nn.attention) and sample (below).  Scopes are metadata
+# attn_proj, kv_pool, paged_attention, mlp (with moe_route, moe_experts,
+# moe_shared in expert layers), lm_head (nn.model, nn.transformer,
+# nn.attention, nn.moe) and sample (below).  Scopes are metadata
 # on the HLO ops: the compiled program is unchanged.
 DECODE_SCOPE = "decode_step"
 
@@ -61,7 +63,9 @@ DECODE_SCOPE = "decode_step"
 def make_serve_step(cfg: ModelConfig):
     def serve_step(params, token, caches, pos):
         with jax.named_scope(DECODE_SCOPE):
-            return decode_step(params, token, caches, pos, cfg)
+            logits, new_caches, _ = decode_step(params, token, caches, pos,
+                                                cfg)
+        return logits, new_caches
     return serve_step
 
 
@@ -194,10 +198,10 @@ def make_paged_serve_step(cfg: ModelConfig, kernel_backend: str = "xla",
                           interpret: bool = False):
     def paged_serve_step(params, token, caches, pos, page_table):
         with jax.named_scope(DECODE_SCOPE):
-            return decode_step(params, token, caches, pos, cfg,
-                               page_table=page_table,
-                               kernel_backend=kernel_backend,
-                               kernel_interpret=interpret)
+            logits, new_caches, _ = decode_step(
+                params, token, caches, pos, cfg, page_table=page_table,
+                kernel_backend=kernel_backend, kernel_interpret=interpret)
+        return logits, new_caches
     return paged_serve_step
 
 
@@ -213,6 +217,21 @@ def cached_paged_serve_step(cfg: ModelConfig, kernel_backend: str = "xla",
                    donate_argnums=(2,))
 
 
+def _counter_names(cfg: ModelConfig):
+    return ("moe_routed_here",) if any(cfg.layer_is_moe) else ()
+
+
+def decode_counters(cfg: ModelConfig, out: np.ndarray):
+    """Split a fused decode step's output into its (B,) token ids and a
+    dict of the (B,) per-row counts the step appends after them: for a
+    model with expert layers "moe_routed_here", each row's top-k slots
+    routed to experts held here, summed over layers."""
+    names = _counter_names(cfg)
+    rows = len(out) // (1 + len(names))
+    return out[:rows], {key: out[(i + 1) * rows:(i + 2) * rows]
+                        for i, key in enumerate(names)}
+
+
 @functools.lru_cache(maxsize=None)
 def cached_fused_paged_serve_step(cfg: ModelConfig,
                                   kernel_backend: str = "xla",
@@ -220,19 +239,25 @@ def cached_fused_paged_serve_step(cfg: ModelConfig,
     """Paged decode step with sampling fused in: logits never leave the
     device — the jitted fn samples every row (greedy/temperature/top-k,
     `serving.sampling.sample_tokens`) and returns only the (B,) int32
-    token ids.  temps (B,) f32, top_ks (B,) int32, keys (B, 2) raw uint32
-    per-request PRNG roots, steps (B,) int32 fold_in indices."""
+    token ids, followed by per-row counts (one array: one device→host
+    transfer; `decode_counters` splits it).  temps (B,) f32, top_ks (B,) int32, keys
+    (B, 2) raw uint32 per-request PRNG roots, steps (B,) int32 fold_in
+    indices."""
     from repro.serving.sampling import sample_tokens
+
+    counters = _counter_names(cfg)
 
     def fused_paged_serve_step(params, token, caches, pos, page_table,
                                temps, top_ks, keys, steps):
         with jax.named_scope(DECODE_SCOPE):
-            logits, new_caches = decode_step(
+            logits, new_caches, aux = decode_step(
                 params, token, caches, pos, cfg, page_table=page_table,
                 kernel_backend=kernel_backend, kernel_interpret=interpret)
             with jax.named_scope("sample"):
                 toks = sample_tokens(logits, cfg.vocab, temperatures=temps,
                                      top_ks=top_ks, keys=keys, steps=steps)
+            if counters:
+                toks = jnp.concatenate([toks, aux["moe_routed"][:, 0]])
         return toks, new_caches
 
     return jax.jit(fused_paged_serve_step, donate_argnums=(2,))

@@ -20,9 +20,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged_attention.ops import mla_decode as _pallas_mla
 from repro.kernels.paged_attention.ops import paged_attention as _pallas_paged
 from repro.nn.config import ModelConfig
-from repro.nn.layers import _init, apply_rope, init_rmsnorm, rmsnorm, rope_angles
+from repro.nn.layers import (_init, apply_rope, init_rmsnorm, rmsnorm,
+                             rope_angles, yarn_mscale)
 from repro.parallel.sharding import shard
 
 Params = Dict[str, Any]
@@ -265,7 +267,7 @@ def _gqa_qkv(params, x, cfg: ModelConfig, positions):
     if cfg.qk_norm:
         q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
         k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
-    cos, sin = rope_angles(positions, D, cfg.rope_theta)
+    cos, sin = rope_angles(positions, D, cfg.rope_theta, cfg.rope_scaling)
     qf = q.reshape(B, S, Hkv * (H // Hkv), D)
     qf = apply_rope(qf, cos, sin).reshape(B, S, Hkv, H // Hkv, D)
     k = apply_rope(k, cos, sin)
@@ -305,11 +307,11 @@ def attention(
       each row's logical blocks to physical pages (cache_pos must be a
       per-row (B,) vector); the returned cache is the whole stack
 
-    kernel_backend="pallas" routes eligible paged GQA decode
-    (single-token, no sliding window, no logit softcap) through the
-    Pallas paged-attention kernel, which walks only the pages at or
-    below each row's position instead of gathering the full table
-    width; everything else falls back to the XLA path.
+    kernel_backend="pallas" routes eligible paged decode (single-token;
+    GQA without sliding window or logit softcap, or MLA) through a Pallas
+    kernel (`paged_attention`, or the latent `mla_decode`), which walks
+    only the pages at or below each row's position instead of gathering
+    the full table width; everything else falls back to the XLA path.
     kernel_interpret is the kernel's interpret mode (the engine passes
     `repro.kernels.interpret_mode()`) — both are trace-time constants.
     """
@@ -328,7 +330,9 @@ def attention(
                               prefix_len=prefix_len, cache=cache,
                               cache_pos=cache_pos, make_cache=make_cache,
                               cache_len=cache_len, page_table=page_table,
-                              cache_layer=cache_layer)
+                              cache_layer=cache_layer,
+                              kernel_backend=kernel_backend,
+                              kernel_interpret=kernel_interpret)
 
     with jax.named_scope("attn_proj"):
         q, k, v = _gqa_qkv(params, x, cfg, positions)
@@ -610,23 +614,62 @@ def _dus_batch(cache: jax.Array, new: jax.Array, pos) -> jax.Array:
 
 
 # ==================================================================== MLA
+def mla_temperature(cfg: ModelConfig) -> float:
+    """YaRN's mscale(factor, mscale_all_dim)² when the rotary embedding is
+    YaRN-scaled with an `mscale_all_dim` (Hugging Face DeepSeek-V2), else
+    1: the factor on MLA's softmax scale."""
+    y = cfg.rope_scaling
+    if y is None or not y.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(d_nope + d_rope)^-1/2 · `mla_temperature`."""
+    return mla_temperature(cfg) / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+
+
+def rope_pool_width(cfg: ModelConfig, platform: Optional[str] = None) -> int:
+    """Lane width of a paged rope-key pool on `platform` (default: JAX's
+    default backend).  A TPU DMA moves whole 128-lane tiles, so on a TPU
+    the latent decode kernel needs the rope dim rounded up to whole tiles
+    (a 64-wide last dim takes 128 lanes in device memory anyway, so the
+    pool's footprint does not change); elsewhere it is the rope dim.
+    Lanes past the rope dim hold zeros."""
+    if (platform or jax.default_backend()) == "tpu":
+        return -(-cfg.qk_rope_dim // 128) * 128
+    return cfg.qk_rope_dim
+
+
+def pad_lanes(x: jax.Array, width: int) -> jax.Array:
+    """Zero-pad the last axis of x to `width`."""
+    extra = width - x.shape[-1]
+    if extra <= 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
+
+
 def _mla_attention(params, x, cfg: ModelConfig, *, positions, prefix_len,
                    cache, cache_pos, make_cache, cache_len,
-                   page_table=None, cache_layer=0):
+                   page_table=None, cache_layer=0, kernel_backend="xla",
+                   kernel_interpret=False):
     B, S, _ = x.shape
     H = cfg.n_heads
     nope, rope_d, vdim = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    scale = mla_softmax_scale(cfg)
 
-    q = jnp.einsum("btd,dq->btq", x, params["w_q"].astype(x.dtype))
-    q = q.reshape(B, S, H, nope + rope_d)
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    cos, sin = rope_angles(positions, rope_d, cfg.rope_theta)
-    q_rope = apply_rope(q_rope, cos, sin)
+    with jax.named_scope("attn_proj"):
+        q = jnp.einsum("btd,dq->btq", x, params["w_q"].astype(x.dtype))
+        q = q.reshape(B, S, H, nope + rope_d)
+        q_nope, q_rope = q[..., :nope], q[..., nope:]
+        cos, sin = rope_angles(positions, rope_d, cfg.rope_theta,
+                               cfg.rope_scaling)
+        q_rope = apply_rope(q_rope, cos, sin)
 
-    c_kv = jnp.einsum("btd,dr->btr", x, params["w_dkv"].astype(x.dtype))
-    c_kv = rmsnorm(params["kv_norm"], c_kv, cfg.norm_eps)
-    k_rope = jnp.einsum("btd,dr->btr", x, params["w_kr"].astype(x.dtype))
-    k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0]
+        c_kv = jnp.einsum("btd,dr->btr", x, params["w_dkv"].astype(x.dtype))
+        c_kv = rmsnorm(params["kv_norm"], c_kv, cfg.norm_eps)
+        k_rope = jnp.einsum("btd,dr->btr", x, params["w_kr"].astype(x.dtype))
+        k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0]
 
     new_cache = None
     chunked = cache is not None and S > 1
@@ -636,44 +679,68 @@ def _mla_attention(params, x, cfg: ModelConfig, *, positions, prefix_len,
         # into the query and W_uv into the output —
         #   score = (q_nope W_ukᵀ)·c_kv + q_rope·k_rope
         #   out   = W_uv (Σ_s p_s c_kv_s)
-        # FLOPs drop from O(S·r·H·(d_nope+d_v)) to O(S·r·H) per step
-        # (≈32× here; EXPERIMENTS.md §Perf iteration 6).
+        # per cached token that is H·(r + d_rope) multiply-adds for the
+        # score and H·r for the sum, against H·r·(d_nope + d_v) to expand
+        # its K and V first (≈100× fewer at DeepSeek-V2-Lite's widths).
+        w_uk = params["w_uk"].astype(jnp.float32).reshape(
+            cfg.kv_lora_rank, H, nope)
+        w_uv = params["w_uv"].astype(jnp.float32).reshape(
+            cfg.kv_lora_rank, H, vdim)
+        with jax.named_scope("attn_proj"):
+            q_lat = jnp.einsum("bshn,rhn->bshr",
+                               q_nope.astype(jnp.float32), w_uk)
+        ctx = None
         if page_table is not None:
             # Paged latent cache: scatter this token's (c_kv, k_rope) into
-            # its physical page of layer `cache_layer`, gather the row's
-            # pages back into logical order, then run the same absorbed
-            # math.
+            # its physical page of layer `cache_layer` in place (the rope
+            # key zero-padded to the pool's lane width)
             scatter, gather = _paged_ops(cache["c_kv"], cache_pos,
                                          page_table, cache_layer)
-            new_cache = {"c_kv": scatter(cache["c_kv"], c_kv),
-                         "k_rope": scatter(cache["k_rope"], k_rope)}
-            ckv_c = gather(new_cache["c_kv"])
-            kr_c = gather(new_cache["k_rope"])
+            width = cache["k_rope"].shape[-1]
+            with jax.named_scope("kv_pool"):
+                new_cache = {"c_kv": scatter(cache["c_kv"], c_kv),
+                             "k_rope": scatter(cache["k_rope"],
+                                               pad_lanes(k_rope, width))}
+            if kernel_backend == "pallas":
+                # the latent kernel walks only each row's live pages of
+                # this layer; W_uv and W_o stay outside it
+                with jax.named_scope("paged_attention"):
+                    ctx = _pallas_mla(
+                        q_lat[:, 0], pad_lanes(q_rope[:, 0], width),
+                        new_cache["c_kv"], new_cache["k_rope"], page_table,
+                        cache_pos, cache_layer, scale=scale,
+                        interpret=kernel_interpret)[:, None]
+            else:
+                # the XLA path (CPU tests): gather the row's pages back
+                # into logical order, then the same absorbed math
+                ckv_c = gather(new_cache["c_kv"])
+                kr_c = gather(new_cache["k_rope"])[..., :rope_d]
         else:
             ckv_c = _dus_batch(cache["c_kv"], c_kv, cache_pos)
             kr_c = _dus_batch(cache["k_rope"], k_rope, cache_pos)
             ckv_c = shard(ckv_c, "batch", "sp", None)
             kr_c = shard(kr_c, "batch", "sp", None)
             new_cache = {"c_kv": ckv_c, "k_rope": kr_c}
-        Sc = ckv_c.shape[1]
-        cp = cache_pos[:, None] if jnp.ndim(cache_pos) else cache_pos
-        valid = (jnp.arange(Sc)[None, :] <= cp)[:, None, :]
-        w_uk = params["w_uk"].astype(jnp.float32).reshape(
-            cfg.kv_lora_rank, H, nope)
-        w_uv = params["w_uv"].astype(jnp.float32).reshape(
-            cfg.kv_lora_rank, H, vdim)
-        q_eff = jnp.einsum("bshn,rhn->bshr", q_nope.astype(jnp.float32), w_uk)
-        scale = 1.0 / math.sqrt(nope + rope_d)
-        s = jnp.einsum("bshr,bkr->bhsk", q_eff, ckv_c.astype(jnp.float32))
-        s = s + jnp.einsum("bshd,bkd->bhsk", q_rope.astype(jnp.float32),
-                           kr_c.astype(jnp.float32))
-        s = s * scale
-        s = jnp.where(valid[:, None], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        ctx = jnp.einsum("bhsk,bkr->bshr", p, ckv_c.astype(jnp.float32))
-        o = jnp.einsum("bshr,rhv->bshv", ctx, w_uv)
-        o = o.astype(x.dtype).reshape(B, S, H * vdim)
-        y = jnp.einsum("btq,qd->btd", o, params["w_o"].astype(x.dtype))
+        if ctx is None:
+            Sc = ckv_c.shape[1]
+            cp = cache_pos[:, None] if jnp.ndim(cache_pos) else cache_pos
+            valid = (jnp.arange(Sc)[None, :] <= cp)[:, None, :]
+            with (jax.named_scope("paged_attention")
+                  if page_table is not None else contextlib.nullcontext()):
+                s = jnp.einsum("bshr,bkr->bhsk", q_lat,
+                               ckv_c.astype(jnp.float32))
+                s = s + jnp.einsum("bshd,bkd->bhsk",
+                                   q_rope.astype(jnp.float32),
+                                   kr_c.astype(jnp.float32))
+                s = s * scale
+                s = jnp.where(valid[:, None], s, NEG_INF)
+                p = jax.nn.softmax(s, axis=-1)
+                ctx = jnp.einsum("bhsk,bkr->bshr", p,
+                                 ckv_c.astype(jnp.float32))
+        with jax.named_scope("attn_proj"):
+            o = jnp.einsum("bshr,rhv->bshv", ctx, w_uv)
+            o = o.astype(x.dtype).reshape(B, S, H * vdim)
+            y = jnp.einsum("btq,qd->btd", o, params["w_o"].astype(x.dtype))
         return y, new_cache
 
     if chunked:
@@ -704,6 +771,11 @@ def _mla_attention(params, x, cfg: ModelConfig, *, positions, prefix_len,
         axis=-1)
     qq = jnp.concatenate([q_nope, q_rope], axis=-1)[:, :, :, None, :]
     qq = qq.reshape(B, S, H, 1, nope + rope_d)
+    if mla_temperature(cfg) != 1.0:
+        # YaRN's softmax temperature: every attend below scales scores by
+        # (d_nope + d_rope)^-1/2 on float32 queries, so the rest of the
+        # scale goes on the queries, in float32
+        qq = qq.astype(jnp.float32) * mla_temperature(cfg)
 
     if chunked:
         # queries sit at `positions`; keys cover the whole staging buffer

@@ -7,6 +7,22 @@ from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN `rope_scaling` (Hugging Face `DeepseekV2YarnRotaryEmbedding`):
+    rotary frequencies ramp from the base ones (dims that turn more than
+    `beta_fast` times over `original_max_position` positions) to the base
+    ones divided by `factor` (fewer than `beta_slow` turns); cos/sin are
+    scaled by mscale(factor, mscale) / mscale(factor, mscale_all_dim), and
+    MLA's softmax scale by mscale(factor, mscale_all_dim)²."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # dense | moe | ssm | hybrid | encoder | vlm
@@ -23,6 +39,7 @@ class ModelConfig:
     causal: bool = True
     qk_norm: bool = False
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnScaling] = None   # None: plain rotary
     sliding_window: int = 0          # 0 = full attention
     global_layers: Tuple[int, ...] = ()  # layers overriding sliding window
     logit_softcap: float = 0.0
@@ -37,6 +54,13 @@ class ModelConfig:
     moe_topk: int = 0
     d_ff_expert: int = 0
     first_dense_layers: int = 0      # leading dense layers (deepseek)
+    # experts this device holds of each expert layer: ids [0, n_held) of
+    # the router's n_experts outputs (0 = all of them).  The layer computes
+    # its held experts' part of the result for the tokens routed to them
+    # (one device's share under expert parallelism, without the exchange)
+    n_experts_held: int = 0
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0   # gate scale when not renormalised
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
 
@@ -88,6 +112,11 @@ class ModelConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def experts_held(self) -> int:
+        """Experts whose weights this device holds (the routed share)."""
+        return self.n_experts_held or self.n_experts
+
+    @property
     def vocab_padded(self) -> int:
         """Vocab padded to a multiple of 256 so it shards over 16-way TP."""
         return math.ceil(self.vocab / 256) * 256
@@ -124,7 +153,8 @@ class ModelConfig:
         dense_ff = ff_mult * d * self.d_ff
         n_moe = sum(self.layer_is_moe)
         n_dense = l - n_moe
-        moe_ff = ff_mult * d * self.d_ff_expert * (self.n_experts + self.n_shared_experts)
+        moe_ff = ff_mult * d * self.d_ff_expert * (self.experts_held
+                                                    + self.n_shared_experts)
         total = emb + l * attn + n_dense * dense_ff + n_moe * moe_ff
         if self.hybrid_parallel:
             di = self.ssm_expand * d
@@ -142,7 +172,7 @@ class ModelConfig:
         d, l = self.d_model, self.n_layers
         full = self.param_count()
         ff_mult = 3 if self.gated else 2
-        moe_all = ff_mult * d * self.d_ff_expert * self.n_experts
+        moe_all = ff_mult * d * self.d_ff_expert * self.experts_held
         moe_active = ff_mult * d * self.d_ff_expert * self.moe_topk
         n_moe = sum(self.layer_is_moe)
         return int(full - n_moe * (moe_all - moe_active))

@@ -7,10 +7,12 @@ structure filled with logical PartitionSpec tuples (consumed by
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.nn.config import ModelConfig
 from repro.parallel.sharding import shard
@@ -70,11 +72,52 @@ def unembed(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 # ---------------------------------------------------------------- Rotary
-def rope_angles(positions: jax.Array, dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
-    """positions (...,) -> cos/sin of shape (..., dim//2)."""
-    freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 · mscale · ln(factor) + 1 (1 when
+    the factor does not stretch)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_inv_freq(dim: int, theta: float, y) -> np.ndarray:
+    """Hugging Face `DeepseekV2YarnRotaryEmbedding`'s inverse frequencies:
+    the base ones for dims below the beta_fast correction dim, the base
+    ones / factor above the beta_slow one, a linear ramp between."""
+    def corr_dim(rotations: float) -> float:
+        return (dim * math.log(y.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(y.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    base = theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    extra, inter = 1.0 / base, 1.0 / (y.factor * base)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def rope_angles(positions: jax.Array, dim: int, theta: float,
+                scaling=None) -> Tuple[jax.Array, jax.Array]:
+    """positions (...,) -> cos/sin of shape (..., dim//2).  `scaling` is a
+    `config.YarnScaling` (None: plain rotary frequencies)."""
+    if scaling is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32)
+                                 / dim))
+    else:
+        freqs = jnp.asarray(_yarn_inv_freq(dim, theta, scaling))
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
+    return cos, sin
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

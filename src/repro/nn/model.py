@@ -99,9 +99,10 @@ def forward(
     page_table=None,
     kernel_backend: str = "xla",
     kernel_interpret: bool = False,
-) -> Tuple[jax.Array, Optional[Any], jax.Array]:
-    """Returns (logits, new_caches, aux_loss).  ``last_only`` restricts the
-    unembed to the final position (prefill/decode)."""
+) -> Tuple[jax.Array, Optional[Any], Dict[str, jax.Array]]:
+    """Returns (logits, new_caches, aux): aux as `nn.transformer.zero_aux`
+    names it, summed over layers.  ``last_only`` restricts the unembed to
+    the final position (prefill/decode)."""
     with jax.named_scope("embed"):
         x, prefix_len = _inputs_to_x(params, batch, cfg)
     x, new_caches, aux = apply_stack(
@@ -182,8 +183,8 @@ def lm_loss(params: Params, batch: Dict[str, jax.Array], cfg: ModelConfig
         mask = mask[:, 1:].astype(jnp.float32)
         ce, cnt = _chunked_ce(params, xx, targets, mask, cfg)
 
-    loss = ce + cfg.router_aux_weight * aux
-    return loss, {"ce": ce, "aux": aux, "tokens": cnt}
+    loss = ce + cfg.router_aux_weight * aux["loss"]
+    return loss, {"ce": ce, "aux": aux["loss"], "tokens": cnt}
 
 
 # ------------------------------------------------------------------ cache
@@ -280,12 +281,13 @@ def decode_step(params: Params, token: jax.Array, caches, pos,
     """One autoregressive step.  token (B,) int32; pos scalar or (B,) int32.
     With ``page_table`` (B, T), caches are page pools and pos must be the
     per-row (B,) write positions (see serving.paging).  kernel_backend
-    routes paged GQA attention through the Pallas kernel (trace-time
-    constant; see nn.attention)."""
+    routes paged attention through the Pallas kernels (trace-time
+    constant; see nn.attention).  Returns (logits, caches, aux), aux the
+    layers' summed aux (`forward`)."""
     batch = {"tokens": token[:, None]}
-    logits, new_caches, _ = forward(params, batch, cfg, caches=caches,
-                                    cache_pos=pos, last_only=True,
-                                    page_table=page_table,
-                                    kernel_backend=kernel_backend,
-                                    kernel_interpret=kernel_interpret)
-    return logits[:, 0], new_caches
+    logits, new_caches, aux = forward(params, batch, cfg, caches=caches,
+                                      cache_pos=pos, last_only=True,
+                                      page_table=page_table,
+                                      kernel_backend=kernel_backend,
+                                      kernel_interpret=kernel_interpret)
+    return logits[:, 0], new_caches, aux

@@ -120,14 +120,17 @@ def apply_block(
     valid_len=None,
     kernel_backend: str = "xla",
     kernel_interpret: bool = False,
-) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """Returns (x, new_cache, aux_loss).  ``valid_len`` marks how many of a
-    chunked-prefill chunk's tokens are real (recurrent layers freeze their
-    state past it; attention masks make it irrelevant there).  With a
-    ``page_table`` the cache holds page pools stacked over layers and this
-    block reads and writes layer ``cache_layer`` of them in place."""
+) -> Tuple[jax.Array, Optional[Params], Dict[str, jax.Array]]:
+    """Returns (x, new_cache, aux): aux as `zero_aux` gives it, this
+    layer's router loss and, in a model with expert layers, the (B, S)
+    count of each token's top-k slots routed to experts held here.
+    ``valid_len`` marks how many of a chunked-prefill chunk's tokens are
+    real (recurrent layers freeze their state past it; attention masks
+    make it irrelevant there).  With a ``page_table`` the cache holds page
+    pools stacked over layers and this block reads and writes layer
+    ``cache_layer`` of them in place."""
     kind = layer_kind(cfg, i)
-    aux = jnp.zeros((), jnp.float32)
+    aux = zero_aux(cfg, x)
     with jax.named_scope("attn_proj"):
         h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
 
@@ -172,8 +175,10 @@ def apply_block(
             # multi-token chunked prefill takes the batch routing path
             # (same numerics as the monolithic prefill); only true
             # one-token steps use the replicated-token decode strategy
-            y2, aux = moe(bp["moe"], h2, cfg,
-                          decode=(cache is not None and x.shape[1] == 1))
+            y2, loss, routed = moe(
+                bp["moe"], h2, cfg,
+                decode=(cache is not None and x.shape[1] == 1))
+            aux = {"loss": loss, "moe_routed": routed}
         else:
             y2 = mlp(bp["mlp"], h2, cfg.act)
         x = x + y2
@@ -182,6 +187,20 @@ def apply_block(
 
 
 # ------------------------------------------------------------- the stack
+def zero_aux(cfg: ModelConfig, x: jax.Array) -> Dict[str, jax.Array]:
+    """A layer's auxiliary outputs before any are added: the router's
+    load-balance loss and, when the model has expert layers, the (B, S)
+    int32 count of top-k slots routed to experts held here."""
+    aux = {"loss": jnp.zeros((), jnp.float32)}
+    if any(cfg.layer_is_moe):
+        aux["moe_routed"] = jnp.zeros(x.shape[:2], jnp.int32)
+    return aux
+
+
+def _add(a, b):
+    return jax.tree.map(jnp.add, a, b)
+
+
 def _block_signature(cfg: ModelConfig, i: int):
     """Layers with equal signatures share block structure (and can scan)."""
     return (layer_kind(cfg, i), cfg.window_for_layer(i), cfg.layer_is_moe[i])
@@ -249,8 +268,8 @@ def apply_stack(
     valid_len=None,
     kernel_backend: str = "xla",
     kernel_interpret: bool = False,
-) -> Tuple[jax.Array, Optional[Any], jax.Array]:
-    aux_total = jnp.zeros((), jnp.float32)
+) -> Tuple[jax.Array, Optional[Any], Dict[str, jax.Array]]:
+    aux_total = zero_aux(cfg, x)
     plan = stack_plan(cfg)
     new_caches: List[Any] = []
     any_cache = False
@@ -281,7 +300,7 @@ def apply_stack(
             else:
                 x, nc, a = block(seg_params, x, cache=seg_cache)
             new_caches.append(nc)
-            aux_total = aux_total + a
+            aux_total = _add(aux_total, a)
             any_cache = any_cache or nc is not None
             continue
 
@@ -296,7 +315,7 @@ def apply_stack(
                     xx_new, nc, a = block(bp, xx, cache=None)
                 if nc is None:
                     nc = jnp.zeros((), jnp.float32)  # scan needs a leaf
-                return (xx_new, aux + a), nc
+                return (xx_new, _add(aux, a)), nc
 
             (x, aux_total), ncs = jax.lax.scan(body, (x, aux_total),
                                                seg_params)
@@ -307,7 +326,7 @@ def apply_stack(
                 bp, layer = layer_in
                 xx_new, pools, a = block(bp, xx, cache=pools,
                                          cache_layer=layer)
-                return (xx_new, aux + a, pools), None
+                return (xx_new, _add(aux, a), pools), None
 
             (x, aux_total, ncs), _ = jax.lax.scan(
                 body, (x, aux_total, seg_cache),
@@ -324,7 +343,7 @@ def apply_stack(
                     xx_new, nc, a = block(bp, xx, cache=layer_cache)
                 if nc is None:
                     nc = jnp.zeros((), jnp.float32)
-                return (xx_new, aux + a), nc
+                return (xx_new, _add(aux, a)), nc
 
             (x, aux_total), ncs = jax.lax.scan(body, (x, aux_total),
                                                (seg_params, seg_cache))

@@ -59,7 +59,7 @@ import dataclasses
 import logging
 import math
 import time
-from typing import Any, Callable, Dict, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -70,7 +70,7 @@ from repro.launch.steps import (cached_chunk_prefill_step,
                                 cached_paged_serve_step, cached_prefill_step,
                                 cached_sample_tokens, cached_serve_step,
                                 cached_stage_install, cached_stage_quantize,
-                                prefill_cache_info)
+                                decode_counters, prefill_cache_info)
 from repro.kernels import interpret_mode
 from repro.nn.config import ModelConfig
 from repro.nn.model import init_cache
@@ -120,9 +120,10 @@ class EngineModel:
     prefix_cache: bool = False
     prefix_cache_pages: int = 0
     # Decode attention backend: "xla" gathers the full page-table width
-    # per step; "pallas" routes paged GQA decode through the
-    # kernels/paged_attention kernel, which walks only each row's live
-    # pages (compiled on TPU, interpreted on CPU — repro.kernels).
+    # per step; "pallas" routes paged decode through the
+    # kernels/paged_attention kernels (GQA `paged_attention`, latent
+    # `mla_decode`), which walk only each row's live pages (compiled on
+    # TPU, interpreted on CPU — repro.kernels).
     kernel_backend: str = "xla"      # "xla" | "pallas"
 
     def __post_init__(self):
@@ -916,6 +917,10 @@ class ServingEngine:
 
         n_decoded = 0
         sample_syncs = 0
+        # per-step counters of the decoded rows: live KV pages (host, from
+        # tables and positions) and, from the fused step's output, the
+        # expert slots routed to experts held here
+        step_counts: Dict[str, int] = {}
         for name in decodable:
             m = self.models[name]
             arena = self.arenas[name]
@@ -968,8 +973,12 @@ class ServingEngine:
                     # for the whole batch (never per row)
                     toks_dev = cached_sample_tokens(m.cfg.vocab)(
                         logits, temps, tks, keys, steps)
-                nxt = np.asarray(toks_dev)
+                nxt, row_counts = np.asarray(toks_dev), {}
+                if fused:
+                    nxt, row_counts = decode_counters(m.cfg, nxt)
                 sample_syncs += 1
+                if self.tracer.enabled:
+                    self._count_step(step_counts, arena, slots, row_counts)
                 for slot in slots:
                     req = self.requests[arena.owner_of(slot)]
                     tok = int(nxt[slot])
@@ -1010,6 +1019,8 @@ class ServingEngine:
             self.tracer.counter("install_queue_depth",
                                 self.pipeline.queue_depth
                                 if self.pipeline is not None else 0)
+            for key, value in step_counts.items():
+                self.tracer.counter(key, value)
         rec = StepRecord(
             t=now,
             n_active=sum(len(a.active_slots()) for a in self.arenas.values()),
@@ -1033,6 +1044,21 @@ class ServingEngine:
             self._observe_step(rec, kv_total - kv_used)
         self._step_no += 1
         self._wall_s += self._clock() - now
+
+    @staticmethod
+    def _count_step(counts: Dict[str, int], arena, slots: List[int],
+                    row_counts: Dict[str, np.ndarray]) -> None:
+        """Add one tenant's decode step to the step's counters: on a paged
+        arena `kv_live_pages`, the pages at or below each decoded row's
+        position; each of a fused step's per-row counts
+        (`decode_counters`) summed over the decoded rows."""
+        if isinstance(arena, PagedKVArena):
+            live = sum(int(arena.pos[r]) // arena.page_size + 1
+                       for r in slots)
+            counts["kv_live_pages"] = counts.get("kv_live_pages", 0) + live
+        for key, per_row in row_counts.items():
+            counts[key] = counts.get(key, 0) + int(
+                sum(int(per_row[r]) for r in slots))
 
     def _observe_step(self, rec: StepRecord, free_pages: int) -> None:
         """Feed the live-telemetry plane after a step: window updates,

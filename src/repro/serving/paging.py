@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.nn.attention import pad_lanes, rope_pool_width
 from repro.nn.config import ModelConfig
 from repro.nn.transformer import layer_kind, stack_plan
 from repro.serving.prefix_cache import RadixPrefixCache
@@ -374,21 +375,25 @@ class PageAllocator:
 
 # ---------------------------------------------------------------- device
 def init_page_pool(cfg: ModelConfig, n_pages: int, page_size: int,
-                   dtype=jnp.bfloat16):
+                   dtype=jnp.bfloat16, platform: Optional[str] = None):
     """Page-pool cache pytree: `nn.model.init_cache` with the batch axis
     reinterpreted as the page axis, except windowed layers keep full pages
     (the paged decode path masks the window instead of ring-indexing) and
     GQA K/V pools flatten the KV heads into the last axis, (P, page,
     Hkv*D): a page is then one lane-dense tile that the Pallas decode
     kernel can DMA whole, and a head_dim of 64 is not padded to the TPU's
-    128 lanes (which doubles a (..., Hkv, D) pool's device footprint)."""
+    128 lanes (which doubles a (..., Hkv, D) pool's device footprint).
+    MLA's rope-key pool is `nn.attention.rope_pool_width` lanes wide on
+    `platform` (default: JAX's default backend), its lanes past the rope
+    dim zero."""
 
     def attn_entry():
         if cfg.attn_type == "mla":
             return {
                 "c_kv": jnp.zeros((n_pages, page_size, cfg.kv_lora_rank),
                                   dtype),
-                "k_rope": jnp.zeros((n_pages, page_size, cfg.qk_rope_dim),
+                "k_rope": jnp.zeros((n_pages, page_size,
+                                     rope_pool_width(cfg, platform)),
                                     dtype),
             }
         kv_dt = jnp.int8 if cfg.kv_cache_dtype == "int8" else dtype
@@ -413,6 +418,19 @@ def init_page_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     return caches
 
 
+def _flat_width(staging_leaf: jax.Array, first: int) -> int:
+    """Width of a staging leaf's per-token entry, its axes from `first`
+    on flattened: the pool lanes that hold data."""
+    return int(np.prod(staging_leaf.shape[first:]))
+
+
+def _to_pool_lanes(chunk: jax.Array, lead: int, width: int) -> jax.Array:
+    """A staging chunk with `lead` leading axes and a per-token entry of
+    any shape, flattened to one axis and zero-padded to the pool's `width`
+    lanes (the rope key; GQA's (Hkv, D) flattens to exactly Hkv*D)."""
+    return pad_lanes(chunk.reshape(chunk.shape[:lead] + (-1,)), width)
+
+
 @functools.lru_cache(maxsize=None)
 def _cached_page_write(cfg: ModelConfig, page_size: int):
     """Jitted page scatter shared across arenas of one config: copy logical
@@ -427,14 +445,17 @@ def _cached_page_write(cfg: ModelConfig, page_size: int):
             def upd(a, o, scanned=scanned):
                 # the reshape flattens staging (..., Hkv, D) K/V into the
                 # pool's (..., Hkv*D) layout (a no-op for other leaves)
+                # and zero-pads a staging rope key to the pool's lanes
                 if scanned:  # a (L, P, ps, ...), o (L, 1, Lbuf, ...)
                     chunk = jax.lax.dynamic_slice_in_dim(
                         o[:, 0], block * page_size, page_size, axis=1)
-                    chunk = chunk.reshape(a.shape[:1] + (1,) + a.shape[2:])
+                    chunk = _to_pool_lanes(chunk, 2, a.shape[-1]).reshape(
+                        a.shape[:1] + (1,) + a.shape[2:])
                     return jax.lax.dynamic_update_slice_in_dim(
                         a, chunk.astype(a.dtype), page, axis=1)
                 chunk = jax.lax.dynamic_slice_in_dim(
                     o[0], block * page_size, page_size, axis=0)
+                chunk = _to_pool_lanes(chunk, 1, a.shape[-1])
                 return jax.lax.dynamic_update_slice_in_dim(
                     a, chunk.reshape((1,) + a.shape[1:]).astype(a.dtype),
                     page, axis=0)
@@ -463,7 +484,9 @@ def _cached_page_read(cfg: ModelConfig, page_size: int):
         for seg_one, seg_pool, (_, _, scanned) in zip(one, pool, plan):
             def upd(o, a, scanned=scanned):
                 # the reshape splits the pool's (..., Hkv*D) K/V back into
-                # staging's (..., Hkv, D) (a no-op for other leaves)
+                # staging's (..., Hkv, D) (a no-op for other leaves); a
+                # rope key drops the pool's padding lanes
+                a = a[..., :_flat_width(o, 3 if scanned else 2)]
                 if scanned:  # a (L, P, ps, ...), o (L, 1, Lbuf, ...)
                     chunk = jax.lax.dynamic_slice_in_dim(a, page, 1, axis=1)
                     chunk = chunk.reshape(chunk.shape[:3] + o.shape[3:])

@@ -88,8 +88,8 @@ def test_prefill_decode_consistency(arch):
             key, (B, cfg.prefix_len, cfg.d_model))
         prefix = cfg.prefix_len
     logits_p, caches = prefill(params, batch, cfg, cache_len=S + 8 + prefix)
-    logits_d, _ = decode_step(params, toks[:, S], caches,
-                              jnp.int32(S + prefix), cfg)
+    logits_d, _, _ = decode_step(params, toks[:, S], caches,
+                                 jnp.int32(S + prefix), cfg)
     # Reference: full forward over S+1 tokens, take last.
     batch2 = dict(batch, tokens=toks)
     ref, _, _ = forward(params, batch2, cfg, last_only=True)
@@ -187,7 +187,7 @@ def test_int8_kv_cache_decode_close_to_bf16(arch):
     outs = {}
     for name, cfg in (("int8", cfg8), ("bf16", cfg16)):
         _, cc = prefill(params, batch, cfg, cache_len=S + 8)
-        ld, _ = decode_step(params, toks[:, S], cc, jnp.int32(S), cfg)
+        ld, _, _ = decode_step(params, toks[:, S], cc, jnp.int32(S), cfg)
         outs[name] = np.asarray(ld, np.float32)
     rel = np.max(np.abs(outs["int8"] - outs["bf16"])) / np.max(
         np.abs(outs["bf16"]))

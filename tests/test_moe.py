@@ -62,11 +62,11 @@ _SUBPROC = textwrap.dedent("""
     y_ref, aux_ref = moe_reference(params, x, cfg)
     mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"))
     with use_mesh(mesh):
-        y, aux = jax.jit(lambda p, v: moe(p, v, cfg))(params, x)
+        y, aux, _ = jax.jit(lambda p, v: moe(p, v, cfg))(params, x)
     err = float(jnp.max(jnp.abs(y - y_ref)))
     assert err < 2e-3, f"a2a path mismatch {err}"
     with use_mesh(mesh):
-        y2, _ = jax.jit(lambda p, v: moe(p, v, cfg, decode=True))(
+        y2, _, _ = jax.jit(lambda p, v: moe(p, v, cfg, decode=True))(
             params, x[:, :1])
     y2_ref, _ = moe_reference(params, x[:, :1], cfg)
     err2 = float(jnp.max(jnp.abs(y2 - y2_ref)))

@@ -177,9 +177,9 @@ def test_paged_decode_matches_contiguous(arch, n_layers):
     tok = jnp.argmax(logits[:, :cfg.vocab], -1).astype(jnp.int32)
     pos = jnp.full((1,), plen, jnp.int32)
     for _ in range(4):                    # crosses into block 2 at pos 8
-        ld_c, caches_c = decode_step(params, tok, caches_c, pos, cfg)
-        ld_p, pool = decode_step(params, tok, pool, pos, cfg,
-                                 page_table=jnp.asarray(tables))
+        ld_c, caches_c, _ = decode_step(params, tok, caches_c, pos, cfg)
+        ld_p, pool, _ = decode_step(params, tok, pool, pos, cfg,
+                                    page_table=jnp.asarray(tables))
         np.testing.assert_array_equal(np.asarray(ld_c, np.float32),
                                       np.asarray(ld_p, np.float32))
         tok = jnp.argmax(ld_c[:, :cfg.vocab], -1).astype(jnp.int32)

@@ -11,18 +11,25 @@ and its layer index, so no layer's pool is sliced out per step.
 
 Grid: (batch,).  Page tables, positions and the layer index arrive
 through scalar prefetch (SMEM), and the pools stay in HBM
-(`memory_space=ANY`): each program walks its row's table and DMAs one
-whole page of K and of V of its layer into VMEM as the loop reaches it,
-double-buffered so page t+1 streams in while page t computes.  Every KV
-head of the row is served from the same page tile: the query arrives as a
-block-diagonal (Hkv, Hkv·D) matrix per query group (row h holds head h's
-query in lanes [h·D, (h+1)·D), zeros elsewhere), so scores are one MXU
-matmul against the page, (Hkv, page), and the probabilities times the
-page give (Hkv, Hkv·D), whose block diagonal is each head's output.  The
-online-softmax recurrence (FlashAttention-2 over a scattered KV layout)
-folds each page into a lane-flat (1, Hkv·D) accumulator.  The fori_loop
-upper bound is pos // page + 1, so pages past the row's position are
-never read.
+(`memory_space=ANY`): each program walks its row's table in blocks of
+several pages (`BLOCK_TOKENS` tokens: 8 pages of 32, 16 of 16), DMAing
+a block's pages of K and of V of its layer into VMEM, double-buffered
+so block t + 1 streams in while block t computes (`page_copies`,
+`page_walk`).  The query arrives grouped by KV head, (Hkv, G, D), and
+each KV head's G queries are served from that head's own lanes
+[h·D, (h+1)·D) of the block: scores (G, D)·(block, D)ᵀ → (G, block),
+then the probabilities times the head's V lanes, (G, block)·(block, D),
+on the MXU with float32 accumulation.  Both products take operands of
+the pool's dtype, as in `mla_decode` (bf16 pools: bf16 operands; float32
+pools stay float32); the probabilities go in as two such terms, their
+rounding and its remainder, stacked into one (2G, block) operand, so
+that a single product keeps them to float32 accuracy (one bf16 rounding
+moves greedy tokens off the XLA path's).  An online softmax
+(FlashAttention-2 over a scattered KV layout) keeps a (G, 1) max and sum
+and a (G, D) float32 accumulator per KV head.  The loop's upper bound is
+the block holding pos; positions past pos in it (the row's stale or
+scratch pages, and the pages past the table's end, which clamp to its
+last) are masked.
 
 Contract: a call on layer l matches
 `repro.kernels.paged_attention.ref.paged_attention_ref` on the pools'
@@ -40,6 +47,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1.0e30
+# tokens a loop step of `paged_attention` reads: the page size sets the
+# pages per block (8 of 32 tokens, 16 of 16)
+BLOCK_TOKENS = 256
 
 
 def page_copies(tab_ref, row, n_blocks: int, layer, pools, bufs, sem,
@@ -96,62 +106,56 @@ def page_walk(copies, hi, bufs, step, init):
 
 
 def _kernel(tab_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
-            v_buf, sem, *, page: int, head_dim: int, n_blocks: int,
+            v_buf, sem, *, page: int, n_blocks: int, per_block: int,
             scale: float):
-    # tab_ref (B*T,) / pos_ref (B,) / layer_ref (1,) in SMEM; q_ref
-    # (1, G, Hkv, Hkv*D) block-diagonal queries; o_ref (1, G, 1, Hkv*D);
-    # k/v_hbm (L, P, page, Hkv*D) in HBM; k/v_buf (2, page, Hkv*D) in VMEM
+    # tab_ref (B*T,) / pos_ref (B,) / layer_ref (1,) in SMEM; q_ref and
+    # o_ref (1, Hkv, G, D); k/v_hbm (L, P, page, Hkv*D) in HBM; k/v_buf
+    # (2, block, Hkv*D) in VMEM
     b = pl.program_id(0)
-    G, Hkv, HD = q_ref.shape[1:]
+    Hkv, G, D = q_ref.shape[1:]
     pos = pos_ref[b]
-    layer = layer_ref[0]
-    hi = pos // page + 1                # blocks holding positions <= pos
-    copies = page_copies(tab_ref, b, n_blocks, layer, (k_hbm, v_hbm),
-                         (k_buf, v_buf), sem)
+    block = per_block * page
+    hi = pos // block + 1               # blocks holding positions <= pos
+    copies = page_copies(tab_ref, b, n_blocks, layer_ref[0], (k_hbm, v_hbm),
+                         (k_buf, v_buf), sem, per_block)
     for c in copies(0, 0):
         c.start()
 
-    # own[h, i]: lane i belongs to head h — picks each head's block of the
-    # (Hkv, Hkv*D) products and spreads per-head scalars over their lanes
-    own = (jax.lax.broadcasted_iota(jnp.int32, (Hkv, HD), 1) // head_dim
-           == jax.lax.broadcasted_iota(jnp.int32, (Hkv, HD), 0))
-
-    def spread(x):                      # (Hkv, 1) -> (1, Hkv*D)
-        return jnp.sum(jnp.where(own, x, 0.0), axis=0, keepdims=True)
-
     def step(t, pages, carry):
-        k, v = pages                                      # (page, Hkv*D)
-        kpos = t * page + jax.lax.broadcasted_iota(jnp.int32, (Hkv, page), 1)
+        k, v = pages                                      # (block, Hkv*D)
+        kpos = t * block + jax.lax.broadcasted_iota(jnp.int32, (G, block), 1)
         out = []
-        for g, (m, l, acc) in enumerate(carry):
+        for h, (m, l, acc) in enumerate(carry):
+            kh = k[:, h * D:(h + 1) * D]                  # (block, D)
+            vh = v[:, h * D:(h + 1) * D]
             s = jax.lax.dot_general(
-                q_ref[0, g].astype(k.dtype), k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (Hkv, page)
+                q_ref[0, h].astype(k.dtype), kh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (G, block)
             s = jnp.where(kpos <= pos, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
-            corr = jnp.exp(m - m_new)                        # (Hkv, 1)
+            corr = jnp.exp(m - m_new)                        # (G, 1)
             l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-            pv = jnp.dot(p, v.astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST,
-                         preferred_element_type=jnp.float32)  # (Hkv, Hkv*D)
-            acc_new = (acc * spread(corr)
-                       + jnp.sum(jnp.where(own, pv, 0.0), axis=0,
-                                 keepdims=True))
-            out.append((m_new, l_new, acc_new))
+            # p as two terms of the pool's dtype, its rounding and the
+            # remainder, stacked into one (2G, block) operand: one MXU
+            # product with the head's V keeps p to float32 accuracy
+            p_hi = p.astype(v.dtype).astype(jnp.float32)
+            pv = jnp.dot(jnp.concatenate([p_hi, p - p_hi]).astype(v.dtype),
+                         vh, preferred_element_type=jnp.float32)
+            pv = pv[:G] + pv[G:]
+            out.append((m_new, l_new, acc * corr + pv))
         return tuple(out)
 
-    init = tuple((jnp.full((Hkv, 1), NEG_INF, jnp.float32),
-                  jnp.zeros((Hkv, 1), jnp.float32),
-                  jnp.zeros((1, HD), jnp.float32)) for _ in range(G))
+    init = tuple((jnp.full((G, 1), NEG_INF, jnp.float32),
+                  jnp.zeros((G, 1), jnp.float32),
+                  jnp.zeros((G, D), jnp.float32)) for _ in range(Hkv))
     final = page_walk(copies, hi, (k_buf, v_buf), step, init)
-    for g, (_, l, acc) in enumerate(final):
-        o_ref[0, g] = (acc / spread(jnp.maximum(l, 1e-30))).astype(
-            o_ref.dtype)
+    for h, (_, l, acc) in enumerate(final):
+        o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(
-    q: jax.Array,        # (B, G, Hkv, D) query heads, group-major
+    q: jax.Array,        # (B, Hkv, G, D) query heads, KV-major
     k_pool: jax.Array,   # (L, P, page, Hkv*D)
     v_pool: jax.Array,
     tables: jax.Array,   # (B, T) int32 physical page ids
@@ -160,38 +164,33 @@ def paged_attention_pallas(
     *,
     interpret: bool,
 ) -> jax.Array:
-    """Returns (B, G, Hkv, D)."""
-    B, G, Hkv, D = q.shape
+    """Returns (B, Hkv, G, D)."""
+    B, Hkv, G, D = q.shape
     page, HD = k_pool.shape[2:]
     T = tables.shape[1]
-    # block-diagonal queries: qd[b, g, h, h'*D + d] = q[b, g, h, d] iff h == h'
-    qd = jnp.einsum("bghd,hk->bghkd", q, jnp.eye(Hkv, dtype=q.dtype))
-    qd = qd.reshape(B, G, Hkv, HD)
-    kern = functools.partial(_kernel, page=page, head_dim=D, n_blocks=T,
+    n = max(1, min(BLOCK_TOKENS // page, T))
+    kern = functools.partial(_kernel, page=page, n_blocks=T, per_block=n,
                              scale=1.0 / math.sqrt(D))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    qo = pl.BlockSpec((1, Hkv, G, D), lambda b, tab, pos, layer: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, G, Hkv, HD),
-                               lambda b, tab, pos, layer: (b, 0, 0, 0)),
-                  hbm, hbm],
-        out_specs=pl.BlockSpec((1, G, 1, HD),
-                               lambda b, tab, pos, layer: (b, 0, 0, 0)),
+        in_specs=[qo, hbm, hbm],
+        out_specs=qo,
         scratch_shapes=[
-            pltpu.VMEM((2, page, HD), k_pool.dtype),
-            pltpu.VMEM((2, page, HD), v_pool.dtype),
+            pltpu.VMEM((2, n * page, HD), k_pool.dtype),
+            pltpu.VMEM((2, n * page, HD), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    o = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, G, 1, HD), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="paged_attention",
     )(tables.reshape(-1), pos, jnp.reshape(layer, (1,)).astype(jnp.int32),
-      qd, k_pool, v_pool)
-    return o.reshape(B, G, Hkv, D)
+      q, k_pool, v_pool)

@@ -1,6 +1,6 @@
-"""Wrappers: `paged_attention` groups query heads per KV head (GQA stays
-native — no pool expansion); `mla_decode` checks the latent kernel's
-shapes; both take the execution mode explicitly.
+"""Wrappers: `paged_attention` hands the kernel the query heads grouped
+by KV head (GQA stays native — no pool expansion); `mla_decode` checks
+the latent kernel's shapes; both take the execution mode explicitly.
 
 No `@jax.jit` here: callers (the serving decode step, the kernel tests)
 jit the surrounding computation, and `interpret` must stay a trace-time
@@ -21,6 +21,13 @@ def paged_attention(q, k_pool, v_pool, tables, pos, layer, *,
     positions; layer: the () int32 index of the layer to read, in place.
     A single layer's pool is passed as a stack of one, at layer 0.
     Returns (B, H, D).
+
+    The kernel serves each KV head's H/Hkv queries (the free reshape
+    (B, Hkv, H/Hkv, D)) from that head's D lanes of the pages, over blocks
+    of several pages (`kernel.BLOCK_TOKENS` tokens, from the page size);
+    both of its products take operands of the pool's dtype with float32
+    accumulation, the probabilities as a high and a low term stacked
+    into one operand.
 
     `interpret=None` resolves from the platform (`repro.kernels.
     interpret_mode`: compiled on TPU, interpreted on CPU); pass an explicit
@@ -47,12 +54,10 @@ def paged_attention(q, k_pool, v_pool, tables, pos, layer, *,
             "int32 (physical page ids)")
     if interpret is None:
         interpret = interpret_mode()
-    G = H // Hkv
-    qg = q.reshape(B, Hkv, G, D).transpose(0, 2, 1, 3)
-    o = paged_attention_pallas(qg, k_pool, v_pool, tables,
-                               pos.astype(jnp.int32), layer,
+    o = paged_attention_pallas(q.reshape(B, Hkv, H // Hkv, D), k_pool,
+                               v_pool, tables, pos.astype(jnp.int32), layer,
                                interpret=bool(interpret))
-    return o.transpose(0, 2, 1, 3).reshape(B, H, D)
+    return o.reshape(B, H, D)
 
 
 def mla_decode(q_lat, q_rope, c_pool, r_pool, tables, pos, layer, *,

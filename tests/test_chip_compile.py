@@ -41,17 +41,23 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
-@pytest.mark.parametrize("B,G,Hkv,D", [
-    (8, 1, 36, 64),     # minicpm-2b: 36 heads of 64, MHA
-    (8, 8, 8, 128),     # a GQA config: 8 KV heads of 128, 8 queries each
-], ids=["minicpm-2b", "gqa-8x128"])
-def test_paged_attention_compiles_for_v5e(one_chip, B, G, Hkv, D):
-    L, page, T = 4, 16, 256
+@pytest.mark.parametrize("B,G,Hkv,D,page,P,T", [
+    # minicpm-2b: 36 heads of 64, MHA
+    (8, 1, 36, 64, 16, FULL_POOL_PAGES, 256),
+    # a GQA config: 8 KV heads of 128, 8 queries each
+    (8, 8, 8, 128, 16, FULL_POOL_PAGES, 256),
+    # yi-34b-s6: 64 rows, 7 queries on each of 8 KV heads of 128, and
+    # every row's table as wide as the pool of 2,560 pages of 32
+    (64, 7, 8, 128, 32, 2561, 2560),
+], ids=["minicpm-2b", "gqa-8x128", "yi-34b-s6"])
+def test_paged_attention_compiles_for_v5e(one_chip, B, G, Hkv, D, page, P,
+                                          T):
+    L = 6
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    pool = s((L, FULL_POOL_PAGES, page, Hkv * D), jnp.bfloat16)
+    pool = s((L, P, page, Hkv * D), jnp.bfloat16)
     compiled = jax.jit(functools.partial(
         paged_attention_pallas, interpret=False)).lower(
-            s((B, G, Hkv, D), jnp.bfloat16), pool, pool,
+            s((B, Hkv, G, D), jnp.bfloat16), pool, pool,
             s((B, T), jnp.int32), s((B,), jnp.int32),
             s((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -74,13 +74,30 @@ def test_flash_attention_kernel_sweep(s, d, causal, dtype):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,h,hkv,d,pool,page,t",
-                         [(3, 4, 2, 32, 9, 4, 6),    # GQA, odd pool
-                          (2, 6, 6, 16, 5, 8, 4),    # MHA
-                          (1, 8, 2, 64, 12, 16, 8)])  # single row, big page
-def test_paged_attention_kernel_sweep(b, h, hkv, d, pool, page, t, dtype):
+@pytest.mark.parametrize("b,h,hkv,d,pool,page,t,pos", [
+    pytest.param(3, 4, 2, 32, 9, 4, 6, None,            # GQA, odd pool
+                 id="3-4-2-32-9-4-6"),
+    pytest.param(2, 6, 6, 16, 5, 8, 4, None,            # MHA
+                 id="2-6-6-16-5-8-4"),
+    pytest.param(1, 8, 2, 64, 12, 16, 8, None,          # one row, big page
+                 id="1-8-2-64-12-16-8"),
+    # yi-34b-s6's grouping, 7 queries on each of 8 KV heads of 128, over
+    # blocks of 8 pages of 32 and a 10-page table (its 2nd block clamped)
+    pytest.param(3, 56, 8, 128, 12, 32, 10, None, id="yi-grouping"),
+    # the same over a 20-page table: a row at position 0, rows on the last
+    # and the first slot of a block, and rows in the 3rd block, whose
+    # pages past the table's 20th clamp to it
+    pytest.param(5, 56, 8, 128, 24, 32, 20, (0, 255, 256, 600, 639),
+                 id="yi-blocks"),
+    # minicpm-2b's 64-lane MHA heads over blocks of 16 pages of 16
+    pytest.param(2, 36, 36, 64, 24, 16, 20, (255, 319),
+                 id="minicpm-blocks"),
+])
+def test_paged_attention_kernel_sweep(b, h, hkv, d, pool, page, t, pos,
+                                      dtype):
     """Decode through scattered page tables must match the gather oracle,
-    including rows whose position sits mid-page (masked tail)."""
+    including rows whose position sits mid-page or mid-block (masked
+    tail), and blocks that reach past the table's end."""
     rng = np.random.default_rng(b * pool + page)
     q = jnp.asarray(rng.standard_normal((b, h, d)), dtype)
     kp = jnp.asarray(rng.standard_normal((pool, page, hkv * d)), dtype)
@@ -88,7 +105,8 @@ def test_paged_attention_kernel_sweep(b, h, hkv, d, pool, page, t, dtype):
     tables = jnp.asarray(
         np.stack([rng.choice(pool, t, replace=False) for _ in range(b)]),
         jnp.int32)
-    pos = jnp.asarray(rng.integers(0, t * page, b), jnp.int32)
+    pos = jnp.asarray(rng.integers(0, t * page, b) if pos is None else pos,
+                      jnp.int32)
     o = paged_attention(q, kp[None], vp[None], tables, pos, 0,
                         interpret=True)
     r = paged_attention_ref(q, kp, vp, tables, pos)
@@ -97,17 +115,16 @@ def test_paged_attention_kernel_sweep(b, h, hkv, d, pool, page, t, dtype):
                                np.asarray(r, np.float32), rtol=tol, atol=tol)
 
 
-def test_paged_attention_masks_stale_pages():
-    """Pages past a row's position may hold arbitrary stale garbage (the
-    freed-page occupancy discipline) without perturbing the output."""
+def _check_stale_pages_masked(b, h, hkv, d, pool, page, t, pos):
+    """Garbage written into the pages past each row's position leaves the
+    kernel's output bit-for-bit unchanged."""
     rng = np.random.default_rng(7)
-    b, h, hkv, d, pool, page, t = 2, 4, 2, 16, 10, 4, 5
     q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
     kp = np.asarray(rng.standard_normal((pool, page, hkv * d)), np.float32)
     vp = np.asarray(rng.standard_normal((pool, page, hkv * d)), np.float32)
     # disjoint tables: a page stale for one row must not be live in another
     tables = rng.permutation(pool).reshape(b, t)
-    pos = np.asarray([5, 9])
+    pos = np.asarray(pos)
     base = paged_attention(q, jnp.asarray(kp)[None], jnp.asarray(vp)[None],
                            jnp.asarray(tables, np.int32),
                            jnp.asarray(pos, np.int32), 0, interpret=True)
@@ -119,6 +136,21 @@ def test_paged_attention_masks_stale_pages():
                           jnp.asarray(tables, np.int32),
                           jnp.asarray(pos, np.int32), 0, interpret=True)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
+
+
+def test_paged_attention_masks_stale_pages():
+    """Pages past a row's position may hold arbitrary stale garbage (the
+    freed-page occupancy discipline) without perturbing the output."""
+    # pages of 4: the 5-page table is one block
+    _check_stale_pages_masked(2, 4, 2, 16, 10, 4, 5, (5, 9))
+
+
+def test_paged_attention_masks_stale_pages_inside_a_block():
+    """The same where the kernel reads the stale pages in one block with
+    live ones: blocks of 8 pages of 32 over a 10-page table, row 0's stale
+    pages 2-7 inside its only block, row 1's page 9 inside its last block
+    (which reads page 9 again for the clamped pages past the table)."""
+    _check_stale_pages_masked(2, 56, 8, 128, 20, 32, 10, (40, 260))
 
 
 @pytest.mark.parametrize("layer", ["first", "middle", "last"])
